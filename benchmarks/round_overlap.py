@@ -32,8 +32,8 @@ measured as 2-3x inflated fused-step latency and a wrecked overlap.
 Compile-once and one-fused-dispatch-per-round must hold in BOTH modes
 (asserted). Writes BENCH_round_overlap.json at the repo root unless
 --smoke, which runs a quick CI check: invariants in both modes plus
-live-device-bytes non-regression of the overlapped mode (double-buffering
-with donated bank buffers must not hold a second bank copy).
+live-device-bytes non-regression of the overlapped mode (between rounds
+the double-buffered schedule must not keep a second bank copy alive).
 
 Usage:  python benchmarks/round_overlap.py [--cohorts 8 32] [--smoke]
 """
@@ -236,7 +236,7 @@ def main():
             f"overlapped {over['s_per_round']*1e3:7.1f} ms/round  "
             f"-> {row['speedup']:.2f}x"
         )
-        # §⑤ double-buffering must not hold a second bank copy
+        # §⑤ double-buffering must not keep a second bank copy alive
         assert over["live_mbytes"] < sync["live_mbytes"] * 1.5 + 64.0, (
             sync["live_mbytes"], over["live_mbytes"])
 

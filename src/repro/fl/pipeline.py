@@ -84,7 +84,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.clustering import _cosine_np
@@ -95,6 +94,11 @@ from repro.kernels import ops as kops
 from repro.launch.mesh import cohort_size, make_cohort_mesh
 from repro.launch.sharding import bank_shardings, row_sharding
 from repro.scale.store import ChunkedAffinityTable
+
+
+# positional arguments of the fused round step that are donated on
+# accelerators: the bank's optimizer state only (see _make_exec_step)
+EXEC_DONATE = (1,)
 
 
 def _next_pow2(n: int) -> int:
@@ -864,28 +868,32 @@ class RoundPipeline:
             sketches = jax.vmap(sketcher)(deltas)
             return new_p, new_o, sketches, losses
 
-        # bparams/bopt are DONATED on accelerators: the step's output bank
-        # reuses the input buffers, so the §⑤ double-buffered schedule
-        # (round r+1 dispatched while round r's outputs are still
-        # referenced by the host) keeps ONE live bank copy instead of two;
-        # sharded in/out specs are identical so donation composes with the
-        # mesh placement. On CPU donation is gated OFF: XLA CPU cannot
+        # Only bopt (EXEC_DONATE) is donated, on accelerators: the step's
+        # output optimizer state reuses the input buffers. bparams is never
+        # donated — the bank it replaces is what `serve_params` publishes
+        # (boundary r-1 while round r is in flight, or the boundary a
+        # caller's snapshot holds across a synchronous step), and a donated
+        # buffer would be deleted under the serving plane. Serving a
+        # snapshot while a round computes the next bank needs both copies
+        # anyway; between rounds the old params are freed as soon as the
+        # snapshot moves on. On CPU donation is gated OFF: XLA CPU cannot
         # donate, and requesting it forces the dispatch to synchronize on
-        # input readiness (measured: a donated 8-device shard_map call
-        # blocks for the full previous-step runtime, serializing the
-        # pipeline this module exists to overlap).
-        donate = {} if jax.default_backend() == "cpu" else {"donate_argnums": (0, 1)}
+        # input readiness, serializing the pipeline this module overlaps.
+        donate = (
+            {} if jax.default_backend() == "cpu"
+            else {"donate_argnums": EXEC_DONATE}
+        )
         if self.n_shards == 1:
             return jax.jit(partial(step, nseg=self.bank.capacity), **donate)
         spec = P("cohort")
-        local = shard_map(
+        local = jax.shard_map(
             partial(step, nseg=self.bank.slots_per_shard),
             mesh=self.mesh,
             # all row/slot inputs shard over the cohort axis; the PRNG seed
             # is replicated (every device re-derives the global key table)
             in_specs=(spec,) * 5 + (P(),) + (spec,) * 4,
             out_specs=(spec,) * 4,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(local, **donate)
 

@@ -5,8 +5,9 @@ Each kernel ships three artifacts:
   ops.py     — jit'd public wrappers (padding, dtype policy, interpret switch)
   ref.py     — pure-jnp oracles used by the property tests
 
-On this CPU container kernels execute via interpret=True; BlockSpecs are
-written for real TPU VMEM (last-dim multiples of 128, f32 accumulation).
+BlockSpecs are written for TPU VMEM (last-dim multiples of 128, f32
+accumulation). On the CPU backend the kernels run with interpret=True; no
+other non-TPU backend is accepted (see ops._interpret).
 """
 from repro.kernels import ops, ref
 

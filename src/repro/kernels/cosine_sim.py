@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(x_ref, c_ref, o_ref, acc, x2, c2, *, nd: int, eps: float):
     d = pl.program_id(1)
@@ -74,7 +72,7 @@ def cosine_similarity(
             pltpu.VMEM((bp, 1), jnp.float32),
             pltpu.VMEM((1, K), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -6,9 +6,16 @@ max `m`, normalizer `l`, and accumulator `acc` live in VMEM scratch across
 KV blocks. This is the compute hot-spot of decode_32k / long_500k serving.
 
 Grid: (B, S/bs) with the KV axis innermost ("arbitrary" semantics).
-Layout: q (B, H, hd), k/v (B, S, Hkv, hd); GQA broadcast done by reshaping
-q to (Hkv, g·hd) tiles — heads stay hardware-aligned when hd is a multiple
-of 128 (ops.py pads).
+
+Layout: the cache's (Hkv, hd) head axes are flattened into one lane axis of
+width W = Hkv·hd, so a k/v block is a plain (bs, W) tile — every block dim
+is aligned to the TPU's (8, 128) tiling, and both matmuls are 2-D. GQA is
+expressed through a block-diagonal query: row h of the (H, W) query holds
+q[h] in the columns of its kv head h // group and zeros elsewhere, so
+`q_bd @ k.T` gives every head's scores against its own kv head (the zero
+columns add exact zeros). `p @ v` yields (H, W); column block h // group of
+row h is head h's output, which the wrapper selects. The per-sequence
+lengths ride in SMEM as a scalar-prefetch operand.
 """
 from __future__ import annotations
 
@@ -20,10 +27,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
-
-def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, acc, m_s, l_s, *, ns: int, hd: int, group: int):
+def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
+            ns: int, hd: int):
+    b = pl.program_id(0)
     s = pl.program_id(1)
 
     @pl.when(s == 0)
@@ -32,24 +39,20 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, acc, m_s, l_s, *, ns: int, hd: 
         m_s[...] = jnp.full_like(m_s, -1e30)
         l_s[...] = jnp.zeros_like(l_s)
 
-    q = q_ref[0].astype(jnp.float32)  # (H, hd) H = Hkv*group
-    k = k_ref[0].astype(jnp.float32)  # (bs, Hkv, hd)
-    v = v_ref[0].astype(jnp.float32)  # (bs, Hkv, hd)
-    bs, hkv, _ = k.shape
+    q = q_ref[0].astype(jnp.float32)  # (H, W) block-diagonal
+    k = k_ref[0].astype(jnp.float32)  # (bs, W)
+    v = v_ref[0].astype(jnp.float32)  # (bs, W)
+    bs = k.shape[0]
     H = q.shape[0]
 
     # scores[h, t] = <q[h], k[t, h // group]> / sqrt(hd)
-    qg = q.reshape(hkv, group, hd)
     scores = jax.lax.dot_general(
-        qg, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
-    )  # (Hkv, group, bs)
-    scores = scores.reshape(H, bs) / math.sqrt(hd)
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) / math.sqrt(hd)  # (H, bs)
 
     # validity: global kv index < cache length
-    t0 = s * bs
-    idx = t0 + jax.lax.broadcasted_iota(jnp.int32, (H, bs), 1)
-    valid = idx < len_ref[0, 0]
-    scores = jnp.where(valid, scores, -1e30)
+    idx = s * bs + jax.lax.broadcasted_iota(jnp.int32, (H, bs), 1)
+    scores = jnp.where(idx < len_ref[b], scores, -1e30)
 
     # streaming softmax update
     m_prev = m_s[...]  # (H, 1)
@@ -57,11 +60,9 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, acc, m_s, l_s, *, ns: int, hd: 
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(scores - m_new)  # (H, bs)
     l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    pg = p.reshape(hkv, group, bs)
-    pv = jax.lax.dot_general(
-        pg, v, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32
-    )  # (Hkv, group, hd)
-    acc[...] = acc[...] * alpha + pv.reshape(H, hd)
+    acc[...] = acc[...] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32
+    )  # (H, W)
     m_s[...] = m_new
 
     @pl.when(s == ns - 1)
@@ -85,29 +86,40 @@ def decode_attention(
     B, H, hd = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     group = H // Hkv
+    W = Hkv * hd
     bs = min(block_s, S)
     assert S % bs == 0, (S, bs)
     ns = S // bs
-    len2d = length.reshape(B, 1).astype(jnp.int32)
+    kv_of = jnp.arange(H) // group  # kv head of each query head
+    q_bd = (
+        q[:, :, None, :]
+        * jax.nn.one_hot(kv_of, Hkv, dtype=q.dtype)[None, :, :, None]
+    ).reshape(B, H, W)
 
-    return pl.pallas_call(
-        functools.partial(_kernel, ns=ns, hd=hd, group=group),
-        grid=(B, ns),
-        in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((1, bs, Hkv, hd), lambda b, s: (b, s, 0, 0)),
-            pl.BlockSpec((1, bs, Hkv, hd), lambda b, s: (b, s, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, s: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, s: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((H, hd), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+    out = pl.pallas_call(
+        functools.partial(_kernel, ns=ns, hd=hd),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, ns),
+            in_specs=[
+                pl.BlockSpec((1, H, W), lambda b, s, n: (b, 0, 0)),
+                pl.BlockSpec((1, bs, W), lambda b, s, n: (b, s, 0)),
+                pl.BlockSpec((1, bs, W), lambda b, s, n: (b, s, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, H, W), lambda b, s, n: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, W), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, W), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(q, k, v, len2d)
+    )(length.astype(jnp.int32), q_bd, k.reshape(B, S, W), v.reshape(B, S, W))
+    # head h's output is column block h // group of its row
+    return jnp.take_along_axis(
+        out.reshape(B, H, Hkv, hd), kv_of[None, :, None, None], axis=2
+    )[:, :, 0]

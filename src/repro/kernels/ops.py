@@ -1,9 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Handles padding to tile multiples, dtype policy, and the CPU fallback:
-on non-TPU backends kernels execute in interpret mode (the kernel body runs
-in Python on CPU), so correctness is validated everywhere while BlockSpecs
-target real TPU VMEM tiling.
+Handles padding to tile multiples and the dtype policy. The kernels are
+compiled for the TPU. On the CPU backend (the test suite) they run in
+Pallas interpret mode, which checks results but not the TPU's tiling or
+VMEM limits (tests/test_tpu_compile.py compiles them for a described
+TPU). Any other backend is refused rather than interpreted.
 """
 from __future__ import annotations
 
@@ -19,7 +20,12 @@ from repro.kernels import segment_aggregate as _sa
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels target the TPU; refusing to interpret them on {backend!r}"
+        )
+    return backend == "cpu"
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:

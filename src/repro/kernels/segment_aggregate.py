@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(data_ref, seg_ref, w_ref, o_ref, acc, *, np_: int, k: int):
     p = pl.program_id(1)
@@ -71,7 +69,7 @@ def segment_aggregate(
         out_specs=pl.BlockSpec((num_segments, bd), lambda d, p: (0, d)),
         out_shape=jax.ShapeDtypeStruct((num_segments, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((num_segments, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -19,12 +19,24 @@ Two mesh families:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes. From jax 0.9 ``make_mesh``
+    defaults to ``Explicit`` axes (sharding in types), under which the
+    bank's slot scatter (``a.at[ii].set(a[ps])``) on a slot-sharded array
+    raises ``ShardingTypeError``; the engine relies on the compiler to
+    partition such ops, which is what ``Auto`` axes do."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(shape), devices=devices
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_cohort_mesh(n_shards: int, *, model: int = 1, devices=None):
@@ -42,10 +54,10 @@ def make_cohort_mesh(n_shards: int, *, model: int = 1, devices=None):
             f"model), only {len(devices)} available"
         )
     if model > 1:
-        return jax.make_mesh(
+        return _auto_mesh(
             (n_shards, model), ("cohort", "model"), devices=devices[:need]
         )
-    return jax.make_mesh((n_shards,), ("cohort",), devices=devices[:need])
+    return _auto_mesh((n_shards,), ("cohort",), devices=devices[:need])
 
 
 def cohort_size(mesh) -> int:

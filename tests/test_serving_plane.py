@@ -24,6 +24,7 @@ from repro.core.clustering import OnlineClustering
 from repro.core.coordinator import CohortStats, PartitionEvent
 from repro.data import make_population
 from repro.fl import AuxoConfig, AuxoEngine, FLConfig
+from repro.fl.pipeline import EXEC_DONATE
 from repro.fl.task import MLPTask
 from repro.models import build_model
 from repro.scale.store import DictProbeCache
@@ -189,6 +190,39 @@ def test_snapshot_follows_partition_flush():
             live.add(eng.pipeline.bank.slot_of["0"])  # generalist fallback
             assert set(slots.tolist()) <= live
     assert flushed >= 1, "scenario must partition mid-flight"
+
+
+@pytest.mark.parametrize("overlap", [0, 1])
+def test_published_snapshot_is_never_donated(overlap):
+    """No leaf of a published `serve_params` snapshot is ever passed in an
+    argument position the fused step donates (`EXEC_DONATE`). On an
+    accelerator such a leaf would be deleted under the serving plane — a
+    snapshot held across a synchronous step, or boundary r-1 served while
+    round r is in flight. Donation is gated off on the CPU, so the check
+    reads the donated arguments of every dispatch instead."""
+    task, pop, fl, auxo = _trained_scenario()
+    eng = AuxoEngine(task, pop, dataclasses.replace(fl, round_overlap=overlap),
+                     auxo)
+    pipe = eng.pipeline
+    step = pipe._exec_step
+    donated = []
+
+    def spy(*args):
+        for i in EXEC_DONATE:
+            donated.extend(jax.tree.leaves(args[i]))
+        return step(*args)
+
+    pipe._exec_step = spy
+    published = list(jax.tree.leaves(pipe.serve_params))
+    for r in range(fl.rounds):
+        eng.step(r)
+        published.extend(jax.tree.leaves(pipe.serve_params))
+    pipe.flush()
+    published.extend(jax.tree.leaves(pipe.serve_params))
+    assert donated and pipe.exec_dispatches == fl.rounds
+    assert len(eng.coordinator.tree.leaves()) >= 2  # spawns ran too
+    donated_ids = {id(a) for a in donated}  # both lists keep every leaf alive
+    assert not [a for a in published if id(a) in donated_ids]
 
 
 # ------------------------------------------------------- admission/batching
