@@ -48,6 +48,7 @@ from repro.kernels import ref as kref  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.serve import CohortDecoder, QueryStream, ServingPlane, StreamConfig  # noqa: E402
 from repro.utils.compile_cache import use_persistent_cache  # noqa: E402
+from repro.utils.trace import compiles  # noqa: E402
 
 # Bank params of the fused step against the sequential oracle, and of the
 # sharded bank against one device. FedYoGi moves a coordinate by at most
@@ -71,28 +72,12 @@ LOGIT_TOL = 0.25
 KERNEL_TOL = 3e-2
 
 
-class CompileClock:
-    """Seconds JAX spends in backend compiles (cache reads included)."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 def report(name: str, t0: float, clock, **fields):
     line = {"phase": name, "wall_s": time.perf_counter() - t0}
     if clock is not None:
-        line |= {"compile_s": clock.seconds, "cache_hits": clock.cache_hits}
+        c = compiles() - clock  # compiles since the run started
+        line |= {"compiles": c.count, "compile_s": c.seconds,
+                 "cache_hits": c.cache_hits}
     print(json.dumps(line | fields), flush=True)
 
 
@@ -314,7 +299,7 @@ def phase_decode(cfg, steps=160, lanes=4, page=128, *, on_tpu, clock=None,
         toks, logits = dec.decode(steps)
         runs[backend] = (toks, np.asarray(logits, np.float32), dec.kv_nbytes,
                          dec.cache.pages, time.perf_counter() - t1)
-        check(dec._step._cache_size() == 1, f"{backend} decode step recompiled")
+        check(dec.step_compiles == 1, f"{backend} decode step recompiled")
         peaks[backend] = device_peak_bytes()
         del dec, c
     (tok_p, lg_p, kv_nbytes, pages, t_p), (tok_r, lg_r, _, _, t_r) = (
@@ -403,7 +388,7 @@ def main(argv=None) -> int:
         print(f"chip_smoke: {args.chips} chips asked, {len(devices)} found",
               file=sys.stderr)
         return 1
-    clock = CompileClock()
+    clock = compiles()
     report("setup", t0, clock, cache_dir=cache_dir, device_kind=dev.device_kind,
            count=len(devices))
 

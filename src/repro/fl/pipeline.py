@@ -78,7 +78,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -883,11 +882,16 @@ class RoundPipeline:
             {} if jax.default_backend() == "cpu"
             else {"donate_argnums": EXEC_DONATE}
         )
+        nseg = self.bank.capacity if self.n_shards == 1 else self.bank.slots_per_shard
+
+        def fused_round_step(*args):  # names the program jit_fused_round_step
+            return step(*args, nseg=nseg)
+
         if self.n_shards == 1:
-            return jax.jit(partial(step, nseg=self.bank.capacity), **donate)
+            return jax.jit(fused_round_step, **donate)
         spec = P("cohort")
         local = jax.shard_map(
-            partial(step, nseg=self.bank.slots_per_shard),
+            fused_round_step,
             mesh=self.mesh,
             # all row/slot inputs shard over the cohort axis; the PRNG seed
             # is replicated (every device re-derives the global key table)
@@ -1084,11 +1088,7 @@ class RoundPipeline:
         partition). Reading `res.sketches` here is the first (lazy) device
         fetch of the round's artifacts.
         """
-        t0 = time.perf_counter()
-        try:
-            return self._apply_feedback(plan, res)
-        finally:
-            self.stage_seconds["feedback"] += time.perf_counter() - t0
+        return self._timed("feedback", self._apply_feedback, plan, res)
 
     def _apply_feedback(self, plan: MatchPlan, res: ExecResult) -> bool:
         eng, fl, auxo = self.eng, self.eng.fl, self.eng.auxo

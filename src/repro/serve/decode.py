@@ -35,11 +35,26 @@ from repro.models.transformer import (
     lm_logits,
 )
 from repro.serve.kv_cache import PagedKVCache
+from repro.utils.trace import span
 
 ATTEND = {
     "pallas": lambda q, k, v, n: kops.decode_attention(q, k, v, n),
     "ref": lambda q, k, v, n: kref.decode_attention(q, k, v, n),
 }
+
+
+@jax.jit
+def gather_bank_rows(bank, slots):
+    """Every leaf's rows at `slots`: the decode's copy of the bank, in one
+    program."""
+    return jax.tree.map(lambda a: a[slots], bank)
+
+
+@jax.jit
+def pick_tokens(logits, index):
+    """Greedy next token (rows, lanes, 1) of a fleet step, and the
+    positions advanced by one."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, :, None], index + 1
 
 
 def make_row_decode_step(cfg, attend: Callable):
@@ -112,8 +127,13 @@ class CohortDecoder:
             page_size=page_size,
             dtype=jnp.float32,
         )
-        # one jitted fleet step; jax retraces per (rows, seq) bucket
-        self._step = jax.jit(jax.vmap(make_row_decode_step(self.cfg, ATTEND[backend])))
+        # one jitted fleet step; jax retraces per (rows, seq) bucket.
+        # `decode` calls `_step`, which a caller may wrap (a timer, a hook);
+        # `_fleet_step` stays the jitted step itself.
+        self._fleet_step = jax.jit(
+            jax.vmap(make_row_decode_step(self.cfg, ATTEND[backend]))
+        )
+        self._step = self._fleet_step
         self.decode_dispatches = 0
         self.tokens: Optional[np.ndarray] = None  # (rows, lanes) last token
 
@@ -132,6 +152,11 @@ class CohortDecoder:
         )
 
     # ------------------------------------------------------------ plumbing
+    @property
+    def step_compiles(self) -> int:
+        """Compiled variants of the fleet step (one per (rows, seq) bucket)."""
+        return self._fleet_step._cache_size()
+
     @property
     def kv_nbytes(self) -> int:
         return self.cache.nbytes
@@ -158,35 +183,44 @@ class CohortDecoder:
 
         Returns (tokens (live_rows, lanes, n_steps) int32,
                  last-step logits (live_rows, lanes, V) float32).
-        One jitted dispatch per step for the WHOLE fleet.
+        One jitted dispatch per step for the WHOLE fleet. Each stage runs in
+        a span (`repro.utils.trace`): ``decode.prepare`` once, then per step
+        ``decode.dispatch`` (the fleet step's call), ``decode.pick`` (the
+        next token) and ``decode.fetch`` (the token to the host), and
+        ``decode.writeback`` once.
         """
-        self.sync()
-        live = self.cache.slots
-        assert live, "no live cohorts to decode"
-        self.cache.ensure(n_steps + 1)
-        r_pad = self.cache.rows
-        # pad rows re-use row 0's slot params; their lanes are discarded
-        slots_p = np.asarray(
-            live + [live[0]] * (r_pad - len(live)), np.int64
-        )
-        if self.tokens is None:
-            self.tokens = self._seed_tokens()
-        tok = np.zeros((r_pad, self.lanes), np.int32)
-        tok[: len(live)] = self.tokens
-        tok = jnp.asarray(tok[:, :, None])  # (R, lanes, 1)
-        params = jax.tree.map(lambda a: a[slots_p], self.params_fn())
-        k, v = self.cache.k, self.cache.v
-        index = jnp.asarray(self.cache.index)
+        with span("decode.prepare"):
+            self.sync()
+            live = self.cache.slots
+            assert live, "no live cohorts to decode"
+            self.cache.ensure(n_steps + 1)
+            r_pad = self.cache.rows
+            # pad rows re-use row 0's slot params; their lanes are discarded
+            slots_p = np.asarray(
+                live + [live[0]] * (r_pad - len(live)), np.int32
+            )
+            if self.tokens is None:
+                self.tokens = self._seed_tokens()
+            tok = np.zeros((r_pad, self.lanes), np.int32)
+            tok[: len(live)] = self.tokens
+            tok = jnp.asarray(tok[:, :, None])  # (R, lanes, 1)
+            params = gather_bank_rows(self.params_fn(), slots_p)
+            k, v = self.cache.k, self.cache.v
+            index = jnp.asarray(self.cache.index)
         out = []
         logits = None
         for _ in range(int(n_steps)):
-            logits, k, v = self._step(params, tok, k, v, index)
+            with span("decode.dispatch"):
+                logits, k, v = self._step(params, tok, k, v, index)
             self.decode_dispatches += 1
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, :, None]
-            index = index + 1
-            out.append(np.asarray(tok)[:, :, 0])
-        self.cache.k, self.cache.v = k, v
-        self.cache.index = np.asarray(index, np.int32)
-        toks = np.stack(out, axis=-1)  # (R, lanes, n_steps)
-        self.tokens = toks[: len(live), :, -1]
-        return toks[: len(live)], np.asarray(logits)[: len(live)]
+            with span("decode.pick"):
+                tok, index = pick_tokens(logits, index)
+            with span("decode.fetch"):
+                out.append(np.asarray(tok)[:, :, 0])
+        with span("decode.writeback"):
+            self.cache.k, self.cache.v = k, v
+            self.cache.index = np.asarray(index, np.int32)
+            toks = np.stack(out, axis=-1)  # (R, lanes, n_steps)
+            self.tokens = toks[: len(live), :, -1]
+            last = np.asarray(logits)[: len(live)]
+        return toks[: len(live)], last
