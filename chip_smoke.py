@@ -288,13 +288,15 @@ def phase_decode(cfg, steps=160, lanes=4, page=128, *, on_tpu, clock=None,
         dec.cache.ensure(steps + 1)
         c = dec.cache
         # one throwaway step at the decode's shapes compiles it outside the
-        # timed loop (the bank already has the gathered rows' shape)
+        # timed loop (the bank already has the gathered rows' shape); the
+        # step consumes the cache it is given, so the cache takes its output
         warm = (bank, jnp.zeros((1, lanes, 1), jnp.int32), c.k, c.v,
                 jnp.asarray(c.index))
-        first[backend] = np.asarray(dec._step(*warm)[0], np.float32)
         if on_tpu and backend == "pallas":
             check_tpu_kernel(dec._step, *warm)
-        del warm
+        logits, c.k, c.v = dec._step(*warm)
+        first[backend] = np.asarray(logits, np.float32)
+        del warm, logits
         t1 = time.perf_counter()
         toks, logits = dec.decode(steps)
         runs[backend] = (toks, np.asarray(logits, np.float32), dec.kv_nbytes,

@@ -7,6 +7,12 @@ KV blocks. This is the compute hot-spot of decode_32k / long_500k serving.
 
 Grid: (B, S/bs) with the KV axis innermost ("arbitrary" semantics).
 
+The cache may be the stack of every layer's cache, (Lk, B, S, W), as the
+paged decode step carries it: the layer rides in SMEM as a scalar-prefetch
+operand and the k/v index maps pick that layer's blocks where they lie, so
+the call copies nothing out of the stack. One layer's (B, S, Hkv, hd)
+cache is the Lk = 1, layer = 0 case.
+
 Layout: the cache's (Hkv, hd) head axes are flattened into one lane axis of
 width W = Hkv·hd, so a k/v block is a plain (bs, W) tile — every block dim
 is aligned to the TPU's (8, 128) tiling, and both matmuls are 2-D. GQA is
@@ -15,7 +21,8 @@ q[h] in the columns of its kv head h // group and zeros elsewhere, so
 `q_bd @ k.T` gives every head's scores against its own kv head (the zero
 columns add exact zeros). `p @ v` yields (H, W); column block h // group of
 row h is head h's output, which the wrapper selects. The per-sequence
-lengths ride in SMEM as a scalar-prefetch operand.
+lengths are an SMEM operand blocked by sequence, so a vmap over the
+caller's rows stays one grid and never loops over rows.
 """
 from __future__ import annotations
 
@@ -28,9 +35,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
+def _kernel(layer_ref, len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
             ns: int, hd: int):
-    b = pl.program_id(0)
+    del layer_ref  # read by the k/v index maps only
     s = pl.program_id(1)
 
     @pl.when(s == 0)
@@ -40,8 +47,8 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
         l_s[...] = jnp.zeros_like(l_s)
 
     q = q_ref[0].astype(jnp.float32)  # (H, W) block-diagonal
-    k = k_ref[0].astype(jnp.float32)  # (bs, W)
-    v = v_ref[0].astype(jnp.float32)  # (bs, W)
+    k = k_ref[...].astype(jnp.float32)  # (bs, W)
+    v = v_ref[...].astype(jnp.float32)  # (bs, W)
     bs = k.shape[0]
     H = q.shape[0]
 
@@ -52,7 +59,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 
     # validity: global kv index < cache length
     idx = s * bs + jax.lax.broadcasted_iota(jnp.int32, (H, bs), 1)
-    scores = jnp.where(idx < len_ref[b], scores, -1e30)
+    scores = jnp.where(idx < len_ref[0, 0], scores, -1e30)
 
     # streaming softmax update
     m_prev = m_s[...]  # (H, 1)
@@ -75,18 +82,25 @@ def decode_attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     length: jnp.ndarray,
+    layer=None,
     *,
     block_s: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """q: (B, H, hd); k, v: (B, S, Hkv, hd); length: (B,) valid KV count.
+    """q: (B, H, hd); length: (B,) valid KV count. k, v: one layer's
+    (B, S, Hkv, hd) cache, or with `layer` the stacked (Lk, B, S, Hkv·hd)
+    cache of which layer `layer` is read in place.
 
-    Returns (B, H, hd). S % block_s == 0 (ops.py pads).
+    Returns (B, H, hd). S % block_s == 0 (ops.py pads a layer's cache).
     """
     B, H, hd = q.shape
-    S, Hkv = k.shape[1], k.shape[2]
+    if layer is None:
+        S, Hkv = k.shape[1], k.shape[2]
+        k, v = k.reshape(1, B, S, Hkv * hd), v.reshape(1, B, S, Hkv * hd)
+        layer = 0
+    S, W = k.shape[2], k.shape[3]
+    Hkv = W // hd
     group = H // Hkv
-    W = Hkv * hd
     bs = min(block_s, S)
     assert S % bs == 0, (S, bs)
     ns = S // bs
@@ -95,6 +109,9 @@ def decode_attention(
         q[:, :, None, :]
         * jax.nn.one_hot(kv_of, Hkv, dtype=q.dtype)[None, :, :, None]
     ).reshape(B, H, W)
+    kv_spec = pl.BlockSpec(
+        (pl.squeezed, pl.squeezed, bs, W), lambda b, s, l: (l[0], b, s, 0)
+    )
 
     out = pl.pallas_call(
         functools.partial(_kernel, ns=ns, hd=hd),
@@ -102,11 +119,13 @@ def decode_attention(
             num_scalar_prefetch=1,
             grid=(B, ns),
             in_specs=[
-                pl.BlockSpec((1, H, W), lambda b, s, n: (b, 0, 0)),
-                pl.BlockSpec((1, bs, W), lambda b, s, n: (b, s, 0)),
-                pl.BlockSpec((1, bs, W), lambda b, s, n: (b, s, 0)),
+                pl.BlockSpec((pl.squeezed, 1, 1), lambda b, s, l: (b, 0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, H, W), lambda b, s, l: (b, 0, 0)),
+                kv_spec,
+                kv_spec,
             ],
-            out_specs=pl.BlockSpec((1, H, W), lambda b, s, n: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, W), lambda b, s, l: (b, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((H, W), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
@@ -118,7 +137,11 @@ def decode_attention(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(length.astype(jnp.int32), q_bd, k.reshape(B, S, W), v.reshape(B, S, W))
+    )(
+        jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+        length.astype(jnp.int32).reshape(B, 1, 1),
+        q_bd, k, v,
+    )
     # head h's output is column block h // group of its row
     return jnp.take_along_axis(
         out.reshape(B, H, Hkv, hd), kv_of[None, :, None, None], axis=2

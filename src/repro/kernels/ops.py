@@ -111,17 +111,23 @@ def decode_attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     length: jnp.ndarray,
+    layer: Optional[jnp.ndarray] = None,
     block_s: int = 512,
 ) -> jnp.ndarray:
     """GQA decode attention over a long KV cache (flash-decode).
 
-    q: (B, H, hd); k, v: (B, S, Hkv, hd); length: scalar or (B,).
-    Pads S to a block multiple (padded slots are masked by `length`).
+    q: (B, H, hd); length: scalar or (B,). k, v: one layer's (B, S, Hkv, hd)
+    cache, padded here to a block multiple (padded slots are masked by
+    `length`); or with `layer`, the paged decode's stacked
+    (Lk, B, S, Hkv·hd) cache, read in place and never padded (its S is a
+    pow2 number of pages, so a block divides it).
     """
-    B, H, hd = q.shape
-    S = k.shape[1]
-    bs = min(block_s, max(128, S))
-    kp = _pad_to(k, 1, bs)
-    vp = _pad_to(v, 1, bs)
+    B = q.shape[0]
     lb = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
-    return _da.decode_attention(q, kp, vp, lb, block_s=bs, interpret=_interpret())
+    if layer is None:
+        bs = min(block_s, max(128, k.shape[1]))
+        k, v = _pad_to(k, 1, bs), _pad_to(v, 1, bs)
+    else:
+        bs = min(block_s, k.shape[2])
+    return _da.decode_attention(q, k, v, lb, layer, block_s=bs,
+                                interpret=_interpret())

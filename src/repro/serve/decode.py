@@ -11,7 +11,11 @@ backends must produce identical greedy token streams.
 
 The per-row step mirrors `models.transformer.decode_step` for the dense
 family, with the ring-buffer `attention_decode` swapped for a paged
-append + length-masked kernel call.
+append + length-masked kernel call. The cache is updated in place: the
+fleet step donates it (on accelerators), the row step carries the whole
+row cache through its layer loop and writes each layer's new position
+with one `dynamic_update_slice`, and the kernel reads each layer's pages
+where they lie in the stacked cache.
 """
 from __future__ import annotations
 
@@ -29,17 +33,27 @@ from repro.models.common import (
     rmsnorm,
     _qkv,
 )
-from repro.models.transformer import (
-    _scan_or_unroll_cache,
-    embed_tokens,
-    lm_logits,
-)
+from repro.models.transformer import embed_tokens, lm_logits
 from repro.serve.kv_cache import PagedKVCache
 from repro.utils.trace import span
 
+
+def _ref_attend(q, kc, vc, layer, n):
+    """The oracle on layer `layer` of a stacked (L, lanes, S, Hkv·hd) cache;
+    it copies the layer out, as the oracle may."""
+    lanes, S, W = kc.shape[1:]
+    shape = (lanes, S, W // q.shape[-1], q.shape[-1])
+    return kref.decode_attention(
+        q, kc[layer].reshape(shape), vc[layer].reshape(shape), n
+    )
+
+
+# attend(q (lanes, H, hd), kc, vc (L, lanes, S, Hkv·hd), layer, length)
 ATTEND = {
-    "pallas": lambda q, k, v, n: kops.decode_attention(q, k, v, n),
-    "ref": lambda q, k, v, n: kref.decode_attention(q, k, v, n),
+    "pallas": lambda q, kc, vc, layer, n: kops.decode_attention(
+        q, kc, vc, n, layer
+    ),
+    "ref": _ref_attend,
 }
 
 
@@ -60,9 +74,11 @@ def pick_tokens(logits, index):
 def make_row_decode_step(cfg, attend: Callable):
     """One cohort row, one decode step. Vmapped over rows by the caller.
 
-    params: one bank row; tokens (lanes, 1) int32;
-    kc/vc (L, lanes, S, Hkv, hd); index scalar int32 (current position).
-    Returns (logits (lanes, V), new kc, new vc).
+    params: one bank row; tokens (lanes, 1) int32; kc/vc (L, lanes, S,
+    Hkv·hd), the row's cache in storage form; index scalar int32 (current
+    position). Returns (logits (lanes, V), kc, vc) with the position
+    written. The cache is carried through the layer loop (not scanned as
+    xs/ys), so XLA updates it in place and never slices a layer out.
     """
     assert cfg.family == "dense", f"paged decode supports dense, got {cfg.family}"
     assert not cfg.sliding_window, "paged decode is full-attention only"
@@ -70,27 +86,31 @@ def make_row_decode_step(cfg, attend: Callable):
     def step(params, tokens, kc, vc, index):
         x = embed_tokens(params, cfg, tokens)  # (lanes, 1, D)
         positions = default_positions(cfg, tokens.shape[0], 1, offset=index)
+        row = (1, tokens.shape[0], 1, kc.shape[-1])  # one position, every lane
 
-        def body(x, pc):
-            p, ck, cv = pc
+        def layer(x, kc, vc, p, l):
             xa = rmsnorm(p["attn_norm"], x, cfg.norm_eps)
             q, k, v = _qkv(p["attn"], cfg, xa, positions)  # (lanes,1,H|Hkv,hd)
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, index, 0, 0)
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, index, 0, 0)
-            )
-            a = attend(q[:, 0], ck, cv, index + 1)  # (lanes, H, hd)
+            at = (l, 0, index, 0)
+            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype).reshape(row), at)
+            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype).reshape(row), at)
+            a = attend(q[:, 0], kc, vc, l, index + 1)  # (lanes, H, hd)
             x = x + jnp.einsum("bhk,hkd->bd", a, p["attn"]["wo"])[:, None, :]
             x = x + mlp(p["mlp"], rmsnorm(p["mlp_norm"], x, cfg.norm_eps))
-            return x, (ck, cv)
+            return x, kc, vc
 
-        x, (ks, vs) = _scan_or_unroll_cache(
-            cfg, body, x, (params["backbone"]["blocks"], kc, vc)
-        )
+        blocks = params["backbone"]["blocks"]
+        n_layers = kc.shape[0]
+        if cfg.unroll:
+            for l in range(n_layers):
+                x, kc, vc = layer(x, kc, vc, jax.tree.map(lambda a: a[l], blocks), l)
+        else:
+            (x, kc, vc), _ = jax.lax.scan(
+                lambda c, xs: (layer(*c, *xs), None),
+                (x, kc, vc), (blocks, jnp.arange(n_layers)),
+            )
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return lm_logits(params, cfg, x)[:, 0], ks, vs
+        return lm_logits(params, cfg, x)[:, 0], kc, vc
 
     return step
 
@@ -129,9 +149,15 @@ class CohortDecoder:
         )
         # one jitted fleet step; jax retraces per (rows, seq) bucket.
         # `decode` calls `_step`, which a caller may wrap (a timer, a hook);
-        # `_fleet_step` stays the jitted step itself.
+        # `_fleet_step` stays the jitted step itself. The cache is donated on
+        # accelerators, so each step writes it in place; on the CPU donation
+        # is gated off, as fl/pipeline.py gates EXEC_DONATE.
+        donate = (
+            {} if jax.default_backend() == "cpu"
+            else {"donate_argnums": (2, 3)}  # kc, vc
+        )
         self._fleet_step = jax.jit(
-            jax.vmap(make_row_decode_step(self.cfg, ATTEND[backend]))
+            jax.vmap(make_row_decode_step(self.cfg, ATTEND[backend])), **donate
         )
         self._step = self._fleet_step
         self.decode_dispatches = 0
@@ -183,7 +209,9 @@ class CohortDecoder:
 
         Returns (tokens (live_rows, lanes, n_steps) int32,
                  last-step logits (live_rows, lanes, V) float32).
-        One jitted dispatch per step for the WHOLE fleet. Each stage runs in
+        One jitted dispatch per step for the WHOLE fleet, which consumes the
+        cache it is given (donated): the arrays `cache.k/.v` held before the
+        call are invalid after it. Each stage runs in
         a span (`repro.utils.trace`): ``decode.prepare`` once, then per step
         ``decode.dispatch`` (the fleet step's call), ``decode.pick`` (the
         next token) and ``decode.fetch`` (the token to the host), and

@@ -1,10 +1,17 @@
 """Paged per-cohort KV cache for the serving plane's decode fast path.
 
 One row of pages per LIVE cohort slot, stacked so the whole fleet decodes
-in one vmapped dispatch: k/v are (R, L, lanes, S, Hkv, hd) with R the
+in one vmapped dispatch: k/v are (R, L, lanes, S, Hkv·hd) with R the
 pow2-bucketed live-cohort count, `lanes` concurrent decode streams per
 cohort, and S a pow2 number of `page_size`-token pages that doubles on
 demand. Resident bytes are therefore ∝ live cohorts — never ∝ N clients.
+
+Storage form: the head axes are flattened into one minor axis of width
+W = Hkv·hd. That shape's device layout is row-major and compact, so the
+decode kernel's (block, W) tile of one layer is the stored bytes as they
+lie. The fleet step donates k/v and returns them updated in place. A
+cache handed in as (R, L, lanes, S, Hkv, hd) is put into storage form
+once, by the next `sync`, and counted in `relayouts`.
 
 Partition/merge discipline: `sync(live_slots)` reconciles rows against
 the current leaf slots with the same scatter idiom `spawn_children` uses
@@ -41,8 +48,9 @@ class PagedKVCache:
         self.page_size = int(page_size)
         self.dtype = dtype
         self.slots: List[int] = []  # row -> cohort bank slot
-        self.k = self.v = None      # (R, L, lanes, S, Hkv, hd)
+        self.k = self.v = None      # (R, L, lanes, S, Hkv·hd)
         self.index = np.zeros(0, np.int32)  # per-row decode position
+        self.relayouts = 0  # caches put into storage form by `sync`
 
     # ------------------------------------------------------------- shape
     @property
@@ -63,8 +71,15 @@ class PagedKVCache:
 
     def _zeros(self, r: int, s: int):
         return jnp.zeros(
-            (r, self.L, self.lanes, s, self.Hkv, self.hd), self.dtype
+            (r, self.L, self.lanes, s, self.Hkv * self.hd), self.dtype
         )
+
+    def _to_storage(self):
+        """k/v handed in as (R, L, lanes, S, Hkv, hd) to storage form: one
+        relayout on the device, counted."""
+        if self.k is not None and self.k.ndim == 6:
+            self.k, self.v = (a.reshape(a.shape[:4] + (-1,)) for a in (self.k, self.v))
+            self.relayouts += 1
 
     # ---------------------------------------------------------- lifecycle
     def sync(self, live_slots: Sequence[int]):
@@ -74,6 +89,7 @@ class PagedKVCache:
         theirs, new slots allocate zeroed rows. No-op when the live set is
         unchanged.
         """
+        self._to_storage()
         live = [int(s) for s in live_slots]
         if live == self.slots and self.k is not None:
             return

@@ -39,3 +39,23 @@ def test_per_sequence_lengths():
     for b in range(B):
         want = ref.decode_attention(q[b:b+1], k[b:b+1], v[b:b+1], lens[b])
         np.testing.assert_allclose(np.asarray(got[b:b+1]), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_stacked_cache_layer_read_in_place(layer, dtype):
+    """A layer of the paged decode's stacked (Lk, B, S, Hkv·hd) cache, read
+    through the layer operand, equals the oracle on that layer's slice."""
+    Lk, B, H, Hkv, hd, S = 3, 2, 8, 2, 64, 256
+    key = jax.random.key(layer)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, hd), dtype)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (Lk, B, S, Hkv * hd), dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (Lk, B, S, Hkv * hd), dtype)
+    lens = jnp.asarray([77, 256])
+    got = ops.decode_attention(q, k, v, lens, jnp.asarray(layer), block_s=128)
+    split = (B, S, Hkv, hd)
+    want = ref.decode_attention(q, k[layer].reshape(split), v[layer].reshape(split), lens)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol
+    )

@@ -391,6 +391,51 @@ def test_paged_decode_pallas_matches_ref_oracle():
     assert dec_p.decode_dispatches == 12
 
 
+@pytest.mark.parametrize("backend", ["pallas", "ref"])
+def test_paged_decode_adopts_a_cache_handed_in_head_split_form(backend):
+    """A cache handed in as (R, L, lanes, S, Hkv, hd), as a caller that
+    fills it itself does, serves what the storage form serves; it is put
+    into storage form once and stays in it."""
+    model = _tiny_lm()
+    cfg = model.cfg
+    bank = _fake_bank(model)
+    live = [0, 2]
+    mk = lambda: CohortDecoder(  # noqa: E731
+        model, lambda: bank, lambda: list(live), lanes=2, page_size=64,
+        backend=backend,
+    )
+    split = (2, cfg.n_layers, 2, 64, cfg.n_kv_heads, cfg.hd)
+    key = jax.random.key(7)
+    kv = [jax.random.normal(jax.random.fold_in(key, i), split) for i in (0, 1)]
+    runs = []
+    for handed in (kv, [a.reshape(split[:4] + (-1,)) for a in kv]):
+        dec = mk()
+        dec.sync()
+        dec.cache.k, dec.cache.v = handed
+        dec.cache.index = np.asarray([40, 9], np.int32)  # a seeded context
+        first = dec.decode(5)
+        assert dec.cache.k.shape == split[:4] + (cfg.n_kv_heads * cfg.hd,)
+        runs.append((first, dec.decode(4), dec.cache.relayouts))
+    (a1, a2, relayouts_split), (b1, b2, relayouts_storage) = runs
+    for got, want in ((a1, b1), (a2, b2)):
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+    assert relayouts_split == 1 and relayouts_storage == 0
+
+
+def test_paged_decode_unrolled_layers_match_scanned():
+    model = _tiny_lm()
+    bank = _fake_bank(model)
+    unrolled = build_model(model.cfg.replace(unroll=True))
+    out = [
+        CohortDecoder(m, lambda: bank, lambda: [1, 3], lanes=2, page_size=64,
+                      backend="pallas").decode(6)
+        for m in (model, unrolled)
+    ]
+    np.testing.assert_array_equal(out[0][0], out[1][0])
+    np.testing.assert_allclose(out[0][1], out[1][1], rtol=1e-5, atol=1e-5)
+
+
 def test_paged_kv_partition_scatter_and_cohort_scaling():
     model = _tiny_lm()
     bank = _fake_bank(model, n_slots=6)

@@ -8,16 +8,24 @@ directly with ``interpret=False`` at the widths the engine and the serving
 plane use, and checks that the compiled program holds the Pallas kernel
 (``tpu_custom_call``).
 
+The paged decode's whole fleet step is compiled too, as the decoder builds
+it for a TPU, to check that it updates the KV cache in place.
+
 This is the only test file that loads the TPU compiler: the topology is
 described inside a module fixture (never at import), so under pytest-xdist
 only the worker given this file loads it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels import cosine_sim, decode_attention, segment_aggregate
+from repro.models import build_model
+from repro.serve import CohortDecoder
 
 
 @pytest.fixture(scope="module")
@@ -98,3 +106,71 @@ def test_decode_attention_compiles(one_chip, arch, seq, dtype):
         ((lanes,), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_decode_attention_stacked_cache_compiles(one_chip, arch):
+    H, Hkv, hd = WIDTHS[arch]
+    lanes, layers, seq = 4, 3, 2048
+
+    def fn(q, k, v, n, layer):
+        return decode_attention.decode_attention(q, k, v, n, layer,
+                                                 interpret=False)
+
+    text = _compiled_text(
+        fn, one_chip,
+        ((lanes, H, hd), jnp.bfloat16),
+        ((layers, lanes, seq, Hkv * hd), jnp.float32),
+        ((layers, lanes, seq, Hkv * hd), jnp.float32),
+        ((lanes,), jnp.int32),
+        ((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_fleet_decode_step_updates_cache_in_place(one_chip, monkeypatch):
+    """The fleet step at granite-3-2b widths (2 layers, a small vocabulary),
+    as the decoder builds it on a TPU: both cache arguments alias outputs,
+    no copy or fusion writes an f32 buffer of a layer's slice or more, and
+    the attention kernel is there under the name the benchmark reads."""
+    lanes, seq = 4, 2048
+    cfg = get_config("granite-3-2b").replace(dtype=jnp.bfloat16, n_layers=2,
+                                             vocab=4096)
+    model = build_model(cfg)
+    # the decoder and the kernel wrapper take their TPU branches: donation
+    # on, Pallas compiled rather than interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dec = CohortDecoder(model, None, lambda: [0], lanes=lanes, page_size=128)
+    shaped = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    bank = jax.eval_shape(lambda k: jax.tree.map(lambda a: a[None], model.init(k)),
+                          jax.random.key(0))
+    bank = jax.tree.map(lambda a: shaped(a.shape, a.dtype), bank)
+    dec.cache.sync([0])
+    dec.cache.ensure(seq)  # the cache's own storage form, at seq positions
+    cache = shaped(dec.cache.k.shape, dec.cache.k.dtype)
+    text = dec._fleet_step.lower(
+        bank, shaped((1, lanes, 1), jnp.int32), cache, cache,
+        shaped((1,), jnp.int32),
+    ).compile().as_text()
+    head = text.splitlines()[0]
+    assert head.startswith("HloModule jit_step,"), head
+    # (a) outputs 1 and 2 (the caches) alias the cache parameters, which
+    # follow the bank's leaves and the tokens
+    kc = len(jax.tree.leaves(bank)) + 1
+    assert "input_output_alias=" in head, "the caches are not donated"
+    alias = head.split("input_output_alias=")[1].split("entry_computation_layout")[0]
+    assert f"{{1}}: ({kc}," in alias and f"{{2}}: ({kc + 1}," in alias, alias
+    # (b) nothing copies a layer's slice of the cache, or more
+    slice_elems = lanes * seq * cfg.n_kv_heads * cfg.hd
+    big = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = (.+?) (copy|copy-start|fusion)\(",
+                         text, re.M):
+        for dims in re.findall(r"f32\[([\d,]+)\]", m.group(2)):
+            n = 1
+            for d in dims.split(","):
+                n *= int(d)
+            if n >= slice_elems:
+                big.append(m.group(1))
+    assert not big, big
+    # (c) the kernel, labelled as the benchmark's trace reader finds it
+    assert re.search(r"%decode_attention(\.\d+)? = .*tpu_custom_call", text)
