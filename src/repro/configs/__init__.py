@@ -1,4 +1,4 @@
-"""Config registry: the 10 assigned architectures + input shapes.
+"""Config registry: the assigned architectures + input shapes.
 
 Every entry cites its source; FULL configs are exercised only via the
 dry-run (ShapeDtypeStruct lowering), reduced variants run on CPU in tests.
@@ -21,6 +21,7 @@ ARCH_IDS = [
     "starcoder2_15b",
     "musicgen_large",
     "qwen3_8b",
+    "granite_4_0_h_micro",
 ]
 
 # canonical dashed ids (CLI --arch) -> module names
@@ -28,7 +29,7 @@ DASHED = {a.replace("_", "-"): a for a in ARCH_IDS}
 
 
 def get_config(arch: str) -> ModelConfig:
-    name = DASHED.get(arch, arch).replace("-", "_")
+    name = DASHED.get(arch, arch).replace("-", "_").replace(".", "_")
     mod = importlib.import_module(f"repro.configs.{name}")
     return mod.CONFIG
 
@@ -63,6 +64,10 @@ def reduce_config(cfg: ModelConfig, seq_friendly: bool = True) -> ModelConfig:
             kw["n_layers"] = 2  # one (dense, moe) pair
     if cfg.family == "hybrid":
         kw["attn_every"] = 1
+        kw["ssm_heads"] = 8
+        kw["ssm_state"] = 16
+    if cfg.family == "mamba_attn":
+        kw["attn_layers"] = (1,)  # [mamba, attention]: one of each kind
         kw["ssm_heads"] = 8
         kw["ssm_state"] = 16
     if cfg.family == "ssm":

@@ -137,6 +137,7 @@ def decode_attention(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="decode_attention",
     )(
         jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
         length.astype(jnp.int32).reshape(B, 1, 1),

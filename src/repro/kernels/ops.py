@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.kernels import cosine_sim as _cs
 from repro.kernels import decode_attention as _da
 from repro.kernels import segment_aggregate as _sa
+from repro.kernels import ssm_decode as _ssm
 
 
 def _interpret() -> bool:
@@ -131,3 +132,31 @@ def decode_attention(
         bs = min(block_s, k.shape[2])
     return _da.decode_attention(q, k, v, lb, layer, block_s=bs,
                                 interpret=_interpret())
+
+
+def _ssm_step_inputs(x, dt, A_log, dt_bias):
+    """Per-channel decay exp(dt·A) and input dt·x of Mamba2's one-token
+    update, (B, H·P) each: dt = softplus(dt + dt_bias) and A = -exp(A_log)
+    are per head, broadcast over the head's P channels."""
+    B, H, P = x.shape
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)  # (B, H)
+    da = jnp.exp(dt * -jnp.exp(A_log))
+    return (jnp.repeat(da, P, axis=-1),
+            jnp.repeat(dt, P, axis=-1) * x.reshape(B, H * P).astype(jnp.float32))
+
+
+@jax.jit
+def ssm_decode(x, dt, A_log, dt_bias, D, b, c, state, layer):
+    """Mamba2's one-token selective state update of layer `layer` of the
+    stacked state (Lm, B, N, H·P) float32, in place (the Pallas kernel).
+
+    x: (B, H, P); dt: (B, H), before dt_bias and softplus; A_log, dt_bias,
+    D: (H,); b, c: (B, N). Returns (y (B, H, P) float32, with the D·x skip,
+    and the state).
+    """
+    B, H, P = x.shape
+    da, dtx = _ssm_step_inputs(x, dt, A_log, dt_bias)
+    y, state = _ssm.ssm_decode(da, dtx, b.astype(jnp.float32),
+                               c.astype(jnp.float32), state, layer,
+                               interpret=_interpret())
+    return y.reshape(B, H, P) + D[:, None] * x.astype(jnp.float32), state

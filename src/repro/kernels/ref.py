@@ -51,3 +51,25 @@ def decode_attention(
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bngs,bsnk->bngk", probs, v.astype(jnp.float32))
     return out.reshape(B, H, hd).astype(q.dtype)
+
+
+def ssm_decode(x, dt, A_log, dt_bias, D, b, c, state, layer):
+    """Mamba2 one-token selective state update oracle.
+
+    x: (B, H, P); dt: (B, H) before dt_bias and softplus; A_log, dt_bias,
+    D: (H,); b, c: (B, N); state: stacked (L, B, N, H·P) float32, of which
+    layer `layer` is updated (a copy, as the oracle may).
+    Returns (y (B, H, P) float32, state).
+    """
+    B, H, P = x.shape
+    N = b.shape[-1]
+    xf = x.astype(jnp.float32)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)  # (B, H)
+    a = jnp.exp(dt * -jnp.exp(A_log))
+    h = state[layer].reshape(B, N, H, P)
+    h = a[:, None, :, None] * h + (
+        b.astype(jnp.float32)[:, :, None, None] * (dt[:, :, None] * xf)[:, None]
+    )
+    y = jnp.einsum("bn,bnhp->bhp", c.astype(jnp.float32), h)
+    y = y + D[None, :, None] * xf
+    return y, state.at[layer].set(h.reshape(B, N, H * P))

@@ -45,6 +45,8 @@ from repro.launch.mesh import data_axes, data_size, model_size
 _STACK2 = ("'mamba'", "'mlstm'")
 _STACK1 = (
     "'blocks'",
+    "'ssm_blocks'",
+    "'attn_blocks'",
     "'dense_blocks'",
     "'moe_blocks'",
     "'mamba_tail'",

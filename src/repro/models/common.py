@@ -28,7 +28,7 @@ class ModelConfig:
     """One config object drives every architecture in the zoo."""
 
     arch_id: str
-    family: str  # dense | moe | ssm | hybrid | vlm | audio
+    family: str  # dense | moe | ssm | hybrid | mamba_attn | vlm | audio
     n_layers: int
     d_model: int
     n_heads: int
@@ -40,6 +40,7 @@ class ModelConfig:
     head_dim: int = 0  # 0 -> d_model // n_heads
     qk_norm: bool = False
     sliding_window: int = 0  # 0 -> full causal attention
+    rope: bool = True  # False: no positional encoding (NoPE)
     rope_theta: float = 10_000.0
     mrope_sections: Tuple[int, int, int] = ()  # Qwen2-VL M-RoPE (t, h, w)
 
@@ -57,7 +58,12 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    ssm_conv_bias: bool = False
+    ssm_norm_eps: float = 0.0  # the gated norm's eps (0 -> norm_eps)
     attn_every: int = 0  # zamba2: shared attention block applied every k layers
+    # mamba_attn (granite-4.0-h): the layers at these indices are attention
+    # blocks, every other layer a Mamba2 mixer; an MLP follows every layer
+    attn_layers: Tuple[int, ...] = ()
     slstm_every: int = 0  # xlstm: one sLSTM per `k` blocks (others mLSTM)
 
     # attention/CE chunking (memory): query-block size for training
@@ -100,6 +106,11 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def ssm_layers(self) -> Tuple[int, ...]:
+        """The Mamba2 layers of a `mamba_attn` model, in order."""
+        return tuple(l for l in range(self.n_layers) if l not in self.attn_layers)
 
     @property
     def is_moe_arch(self) -> bool:
@@ -220,6 +231,8 @@ def attention_init(key, cfg: ModelConfig):
 
 
 def _rotate(cfg: ModelConfig, x, positions):
+    if not cfg.rope:
+        return x
     if cfg.mrope_sections:
         return apply_mrope(x, positions, cfg.rope_theta, cfg.mrope_sections)
     return apply_rope(x, positions, cfg.rope_theta)

@@ -18,7 +18,14 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ModelConfig, dense_init, rmsnorm, rmsnorm_init
+from repro.models.common import (
+    ModelConfig,
+    dense_init,
+    mlp,
+    mlp_init,
+    rmsnorm,
+    rmsnorm_init,
+)
 
 
 # ---------------------------------------------------------------------------
@@ -117,11 +124,16 @@ def mamba2_dims(cfg: ModelConfig) -> Tuple[int, int, int, int]:
     return d_inner, H, P, N
 
 
+def gated_eps(cfg: ModelConfig) -> float:
+    """The gated RMSNorm's eps (its input is not the residual stream)."""
+    return cfg.ssm_norm_eps or cfg.norm_eps
+
+
 def mamba2_init(key, cfg: ModelConfig):
     d_inner, H, P, N = mamba2_dims(cfg)
     conv_ch = d_inner + 2 * N
     k_in, k_conv, k_dt, k_out = jax.random.split(key, 4)
-    return {
+    p = {
         "norm": rmsnorm_init(cfg.d_model, cfg.dtype),
         # order: [z (gate), x, B, C, dt]
         "w_in": dense_init(k_in, (cfg.d_model, 2 * d_inner + 2 * N + H), cfg.dtype),
@@ -132,15 +144,21 @@ def mamba2_init(key, cfg: ModelConfig):
         "gated_norm": rmsnorm_init(d_inner, cfg.dtype),
         "w_out": dense_init(k_out, (d_inner, cfg.d_model), cfg.dtype),
     }
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = jnp.zeros((conv_ch,), cfg.dtype)
+    return p
 
 
-def _causal_conv(seq, w, carry=None):
-    """Depthwise causal conv. seq: (b,l,ch); w: (kw,ch); carry: (b,kw-1,ch)."""
+def _causal_conv(seq, w, carry=None, bias=None):
+    """Depthwise causal conv. seq: (b,l,ch); w: (kw,ch); carry: (b,kw-1,ch);
+    bias: (ch,) or None."""
     kw = w.shape[0]
     if carry is None:
         carry = jnp.zeros((seq.shape[0], kw - 1, seq.shape[2]), seq.dtype)
     padded = jnp.concatenate([carry, seq], axis=1)
     out = sum(padded[:, i : i + seq.shape[1]] * w[i] for i in range(kw))
+    if bias is not None:
+        out = out + bias
     new_carry = padded[:, -(kw - 1) :] if kw > 1 else carry
     return jax.nn.silu(out), new_carry
 
@@ -154,7 +172,7 @@ def mamba2_apply(params, cfg: ModelConfig, x):
         zxbcdt, [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N], axis=-1
     )
     conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)
-    conv_out, _ = _causal_conv(conv_in, params["conv_w"])
+    conv_out, _ = _causal_conv(conv_in, params["conv_w"], bias=params.get("conv_b"))
     xin, Bc, Cc = jnp.split(conv_out, [d_inner, d_inner + N], axis=-1)
 
     b, l, _ = x.shape
@@ -168,7 +186,7 @@ def mamba2_apply(params, cfg: ModelConfig, x):
     y, _ = ssd_chunked(xh * dt[..., None].astype(x.dtype), a, Bh, Ch, cfg.ssm_chunk)
     y = y + xh * params["D"][None, None, :, None].astype(x.dtype)
     y = y.reshape(b, l, d_inner)
-    y = rmsnorm(params["gated_norm"], y * jax.nn.silu(z), cfg.norm_eps)
+    y = rmsnorm(params["gated_norm"], y * jax.nn.silu(z), gated_eps(cfg))
     return x + jnp.einsum("ble,ed->bld", y, params["w_out"])
 
 
@@ -190,7 +208,9 @@ def mamba2_decode(params, cfg: ModelConfig, x, cache):
         zxbcdt, [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N], axis=-1
     )
     conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)
-    conv_out, new_conv = _causal_conv(conv_in, params["conv_w"], cache["conv"])
+    conv_out, new_conv = _causal_conv(
+        conv_in, params["conv_w"], cache["conv"], params.get("conv_b")
+    )
     xin, Bc, Cc = jnp.split(conv_out[:, 0], [d_inner, d_inner + N], axis=-1)
 
     b = x.shape[0]
@@ -203,9 +223,32 @@ def mamba2_decode(params, cfg: ModelConfig, x, cache):
     y, new_ssm = ssd_step(cache["ssm"], xh, a, Bh, Ch)
     y = y + xin.reshape(b, H, P) * params["D"][None, :, None].astype(x.dtype)
     y = y.reshape(b, 1, d_inner)
-    y = rmsnorm(params["gated_norm"], y * jax.nn.silu(z), cfg.norm_eps)
+    y = rmsnorm(params["gated_norm"], y * jax.nn.silu(z), gated_eps(cfg))
     out = x + jnp.einsum("ble,ed->bld", y, params["w_out"])
     return out, {"conv": new_conv, "ssm": new_ssm}
+
+
+# Mamba2 mixer + MLP (granite-4.0-h's layer of the mamba kind)
+def mamba2_mlp_init(key, cfg: ModelConfig):
+    k_m, k_f = jax.random.split(key)
+    return {
+        "mixer": mamba2_init(k_m, cfg),
+        "mlp_norm": rmsnorm_init(cfg.d_model, cfg.dtype),
+        "mlp": mlp_init(k_f, cfg),
+    }
+
+
+def _mlp_after(params, cfg: ModelConfig, x):
+    return x + mlp(params["mlp"], rmsnorm(params["mlp_norm"], x, cfg.norm_eps))
+
+
+def mamba2_mlp_apply(params, cfg: ModelConfig, x):
+    return _mlp_after(params, cfg, mamba2_apply(params["mixer"], cfg, x))
+
+
+def mamba2_mlp_decode(params, cfg: ModelConfig, x, cache):
+    x, cache = mamba2_decode(params["mixer"], cfg, x, cache)
+    return _mlp_after(params, cfg, x), cache
 
 
 # ---------------------------------------------------------------------------

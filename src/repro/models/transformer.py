@@ -7,6 +7,9 @@ O(sqrt-ish) via rematerialization. Heterogeneous stacks (zamba2's shared
 attention, xlstm's mLSTM/sLSTM pattern, llama4's dense/MoE alternation) are
 expressed as *superblocks*: the scan unit contains one of each sub-layer
 type, so every scan step has homogeneous parameter shapes and no lax.cond.
+granite-4.0-h's pattern (`mamba_attn`: attention at the layers listed in
+`attn_layers`, Mamba2 elsewhere, an MLP after each) runs as segments: each
+run of Mamba2 layers is one scan, each attention layer is applied alone.
 """
 from __future__ import annotations
 
@@ -110,6 +113,14 @@ def backbone_init(key, cfg: ModelConfig) -> Dict[str, Any]:
                 keys[1], leftover, lambda k: ssm.mamba2_init(k, cfg)
             )
         p["shared_attn"] = block_init(keys[2], cfg)  # one copy, reused
+    elif f == "mamba_attn":
+        p["ssm_blocks"] = _stacked_init(
+            keys[0], len(cfg.ssm_layers), lambda k: ssm.mamba2_mlp_init(k, cfg)
+        )
+        if cfg.attn_layers:
+            p["attn_blocks"] = _stacked_init(
+                keys[1], len(cfg.attn_layers), lambda k: block_init(k, cfg)
+            )
     elif f == "ssm":
         # xlstm: groups of (slstm_every-1 mLSTM + 1 sLSTM).
         g = cfg.slstm_every
@@ -158,6 +169,29 @@ def _scan_or_unroll(cfg: ModelConfig, body, carry, stacked):
         p_i = jax.tree.map(lambda a: a[i], stacked)
         carry, _ = body(carry, p_i)
     return carry
+
+
+def layer_segments(cfg: ModelConfig):
+    """A `mamba_attn` stack in layer order: ("ssm", first, stop) for a run of
+    the stacked Mamba2 layers, ("attn", i) for the i-th attention layer."""
+    segs, start = [], 0
+    for i, layer in enumerate(cfg.attn_layers):
+        stop = layer - i  # Mamba2 layers below this one
+        if stop > start:
+            segs.append(("ssm", start, stop))
+        segs.append(("attn", i))
+        start = stop
+    if len(cfg.ssm_layers) > start:
+        segs.append(("ssm", start, len(cfg.ssm_layers)))
+    return segs
+
+
+def _take(tree, i):
+    return jax.tree.map(lambda a: a[i], tree)
+
+
+def _rows(tree, start, stop):
+    return jax.tree.map(lambda a: a[start:stop], tree)
 
 
 def backbone_apply(params, cfg: ModelConfig, x, positions, window: int = -1):
@@ -226,6 +260,24 @@ def backbone_apply(params, cfg: ModelConfig, x, positions, window: int = -1):
                 return ssm.mamba2_apply(pm, cfg, x), None
 
             x = _scan_or_unroll(cfg, tail, x, params["mamba_tail"])
+
+    elif f == "mamba_attn":
+
+        @ckpt
+        def ssm_body(x, p):
+            return ssm.mamba2_mlp_apply(p, cfg, x), None
+
+        @ckpt
+        def attn_body(x, p):
+            return block_apply(p, cfg, x, positions, window)
+
+        for seg in layer_segments(cfg):
+            if seg[0] == "attn":
+                x = attn_body(x, _take(params["attn_blocks"], seg[1]))
+            else:
+                x = _scan_or_unroll(
+                    cfg, ssm_body, x, _rows(params["ssm_blocks"], *seg[1:])
+                )
 
     elif f == "ssm":
 
@@ -376,6 +428,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None):
         if leftover:
             c["mamba_tail"] = stack(leftover, lambda: ssm.mamba2_cache_init(cfg, batch, dtype))
         return c
+    if f == "mamba_attn":
+        return {
+            "ssm": stack(len(cfg.ssm_layers), lambda: ssm.mamba2_cache_init(cfg, batch, dtype)),
+            "attn": stack(
+                len(cfg.attn_layers),
+                lambda: attention_cache_init(cfg, batch, max_seq, dtype),
+            ),
+        }
     if f == "ssm":
         g = cfg.slstm_every
         n_groups = cfg.n_layers // g
@@ -464,6 +524,32 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, window: int = -1):
 
             x, cts = _scan_or_unroll_cache(cfg, tail, x, (bb["mamba_tail"], cache["mamba_tail"]))
             new_cache["mamba_tail"] = cts
+
+    elif f == "mamba_attn":
+
+        def ssm_body(x, pc):
+            p, c = pc
+            return ssm.mamba2_mlp_decode(p, cfg, x, c)
+
+        ssm_caches, attn_caches = [], []
+        for seg in layer_segments(cfg):
+            if seg[0] == "attn":
+                x, c = block_decode(
+                    _take(bb["attn_blocks"], seg[1]), cfg, x,
+                    _take(cache["attn"], seg[1]), window,
+                )
+                attn_caches.append(c)
+            else:
+                x, c = _scan_or_unroll_cache(
+                    cfg, ssm_body, x,
+                    (_rows(bb["ssm_blocks"], *seg[1:]), _rows(cache["ssm"], *seg[1:])),
+                )
+                ssm_caches.append(c)
+        new_cache = {
+            "ssm": jax.tree.map(lambda *a: jnp.concatenate(a), *ssm_caches),
+            "attn": (jax.tree.map(lambda *a: jnp.stack(a), *attn_caches)
+                     if attn_caches else cache["attn"]),
+        }
 
     elif f == "ssm":
 

@@ -16,6 +16,13 @@ fleet step donates it (on accelerators), the row step carries the whole
 row cache through its layer loop and writes each layer's new position
 with one `dynamic_update_slice`, and the kernel reads each layer's pages
 where they lie in the stacked cache.
+
+A Mamba2 hybrid (`mamba_attn`, granite-4.0-h) runs its layer pattern in
+`make_hybrid_row_step`: the attention layers as above, on their own paged
+cache, and each Mamba2 layer through `kernels.ops.ssm_decode`, which updates
+that layer's recurrent state where it lies in the stacked, donated state
+(`kernels.ref.ssm_decode` is its oracle), beside a convolution window
+written in place with one `dynamic_update_slice`.
 """
 from __future__ import annotations
 
@@ -33,7 +40,8 @@ from repro.models.common import (
     rmsnorm,
     _qkv,
 )
-from repro.models.transformer import embed_tokens, lm_logits
+from repro.models import ssm as ssm_model
+from repro.models.transformer import embed_tokens, layer_segments, lm_logits
 from repro.serve.kv_cache import PagedKVCache
 from repro.utils.trace import span
 
@@ -57,6 +65,19 @@ ATTEND = {
 }
 
 
+# update(x (lanes, H, P), dt (lanes, H), A_log, dt_bias, D, b, c (lanes, N),
+#        state (Lm, lanes, N, H·P), layer) -> (y (lanes, H, P), state)
+UPDATE = {"pallas": kops.ssm_decode, "ref": kref.ssm_decode}
+
+
+def check_supported(cfg):
+    """Paged decode runs dense models and Mamba2 hybrids, full attention."""
+    assert cfg.family in ("dense", "mamba_attn"), (
+        f"paged decode supports dense and mamba_attn, got {cfg.family}"
+    )
+    assert not cfg.sliding_window, "paged decode is full-attention only"
+
+
 @jax.jit
 def gather_bank_rows(bank, slots):
     """Every leaf's rows at `slots`: the decode's copy of the bank, in one
@@ -71,6 +92,27 @@ def pick_tokens(logits, index):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, :, None], index + 1
 
 
+def _attention_layer(cfg, attend: Callable, lanes: int, index):
+    """Layer `l` of the paged cache kc/vc (La, lanes, S, Hkv·hd): an
+    attention block and its MLP at position `index`, the position written
+    in place. Returns (x, kc, vc)."""
+    positions = default_positions(cfg, lanes, 1, offset=index)
+
+    def layer(x, kc, vc, p, l):
+        row = (1, lanes, 1, kc.shape[-1])  # one position, every lane
+        xa = rmsnorm(p["attn_norm"], x, cfg.norm_eps)
+        q, k, v = _qkv(p["attn"], cfg, xa, positions)  # (lanes,1,H|Hkv,hd)
+        at = (l, 0, index, 0)
+        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype).reshape(row), at)
+        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype).reshape(row), at)
+        a = attend(q[:, 0], kc, vc, l, index + 1)  # (lanes, H, hd)
+        x = x + jnp.einsum("bhk,hkd->bd", a, p["attn"]["wo"])[:, None, :]
+        x = x + mlp(p["mlp"], rmsnorm(p["mlp_norm"], x, cfg.norm_eps))
+        return x, kc, vc
+
+    return layer
+
+
 def make_row_decode_step(cfg, attend: Callable):
     """One cohort row, one decode step. Vmapped over rows by the caller.
 
@@ -80,25 +122,12 @@ def make_row_decode_step(cfg, attend: Callable):
     written. The cache is carried through the layer loop (not scanned as
     xs/ys), so XLA updates it in place and never slices a layer out.
     """
-    assert cfg.family == "dense", f"paged decode supports dense, got {cfg.family}"
-    assert not cfg.sliding_window, "paged decode is full-attention only"
+    check_supported(cfg)
+    assert cfg.family == "dense", "a mamba_attn model steps in make_hybrid_row_step"
 
     def step(params, tokens, kc, vc, index):
         x = embed_tokens(params, cfg, tokens)  # (lanes, 1, D)
-        positions = default_positions(cfg, tokens.shape[0], 1, offset=index)
-        row = (1, tokens.shape[0], 1, kc.shape[-1])  # one position, every lane
-
-        def layer(x, kc, vc, p, l):
-            xa = rmsnorm(p["attn_norm"], x, cfg.norm_eps)
-            q, k, v = _qkv(p["attn"], cfg, xa, positions)  # (lanes,1,H|Hkv,hd)
-            at = (l, 0, index, 0)
-            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype).reshape(row), at)
-            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype).reshape(row), at)
-            a = attend(q[:, 0], kc, vc, l, index + 1)  # (lanes, H, hd)
-            x = x + jnp.einsum("bhk,hkd->bd", a, p["attn"]["wo"])[:, None, :]
-            x = x + mlp(p["mlp"], rmsnorm(p["mlp_norm"], x, cfg.norm_eps))
-            return x, kc, vc
-
+        layer = _attention_layer(cfg, attend, tokens.shape[0], index)
         blocks = params["backbone"]["blocks"]
         n_layers = kc.shape[0]
         if cfg.unroll:
@@ -111,6 +140,87 @@ def make_row_decode_step(cfg, attend: Callable):
             )
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         return lm_logits(params, cfg, x)[:, 0], kc, vc
+
+    return step
+
+
+def make_hybrid_row_step(cfg, attend: Callable, update: Callable):
+    """One cohort row, one decode step of a Mamba2 hybrid (`mamba_attn`).
+    Vmapped over rows by the caller.
+
+    params: one bank row; tokens (lanes, 1) int32; kc/vc (La, lanes, S,
+    Hkv·hd), the attention layers' paged cache; ssm (Lm, lanes, N, H·P) and
+    conv (Lm, d_conv - 1, lanes, conv channels), the Mamba2 layers' state
+    (`serve/kv_cache.py`);
+    index scalar int32. Returns (logits (lanes, V), kc, vc, ssm, conv). The
+    layers run in the model's order: each run of Mamba2 layers is a loop
+    over their indices carrying the whole state (a scan over indices, not
+    over the state as xs/ys), each attention layer is applied alone. Every
+    state and page is written where it lies.
+    """
+    check_supported(cfg)
+    d_inner, H, P, N = ssm_model.mamba2_dims(cfg)
+    eps = ssm_model.gated_eps(cfg)
+    ring = cfg.ssm_conv - 1
+
+    def mamba_layer(x, ssm, conv, p, m, index):
+        mx = p["mixer"]
+        lanes = x.shape[0]
+        h = rmsnorm(mx["norm"], x, cfg.norm_eps)[:, 0]  # (lanes, D)
+        zxbcdt = jnp.einsum("bd,de->be", h, mx["w_in"])
+        z, xbc, dt = jnp.split(zxbcdt, [d_inner, 2 * d_inner + 2 * N], axis=-1)
+        # causal conv over the last d_conv positions: conv[m] is a ring in
+        # which position p lies in slot p % ring; this position's input
+        # overwrites the oldest slot
+        xbc = xbc.astype(jnp.float32)
+        w = mx["conv_w"].astype(jnp.float32)  # (d_conv, C)
+        out = xbc * w[ring]
+        for j in range(ring):  # position index - ring + j
+            slot = jax.lax.dynamic_slice(
+                conv, (m, (index + j) % ring, 0, 0), (1, 1) + conv.shape[2:]
+            )
+            out = out + slot[0, 0] * w[j]
+        if "conv_b" in mx:
+            out = out + mx["conv_b"].astype(jnp.float32)
+        out = jax.nn.silu(out)
+        conv = jax.lax.dynamic_update_slice(
+            conv, xbc[None, None], (m, index % ring, 0, 0)
+        )
+        xs, b, c = jnp.split(out, [d_inner, d_inner + N], axis=-1)
+        y, ssm = update(xs.reshape(lanes, H, P), dt, mx["A_log"], mx["dt_bias"],
+                        mx["D"], b, c, ssm, m)
+        y = y.reshape(lanes, d_inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = rmsnorm(mx["gated_norm"], y, eps).astype(x.dtype)
+        x = x + jnp.einsum("be,ed->bd", y, mx["w_out"])[:, None, :]
+        x = x + mlp(p["mlp"], rmsnorm(p["mlp_norm"], x, cfg.norm_eps))
+        return x, ssm, conv
+
+    def step(params, tokens, kc, vc, ssm, conv, index):
+        x = embed_tokens(params, cfg, tokens)  # (lanes, 1, D)
+        attn_layer = _attention_layer(cfg, attend, tokens.shape[0], index)
+        bb = params["backbone"]
+        blocks = bb["ssm_blocks"]
+        for seg in layer_segments(cfg):
+            if seg[0] == "attn":
+                p = jax.tree.map(lambda a: a[seg[1]], bb["attn_blocks"])
+                x, kc, vc = attn_layer(x, kc, vc, p, seg[1])
+            elif cfg.unroll:
+                for m in range(*seg[1:]):
+                    p = jax.tree.map(lambda a: a[m], blocks)
+                    x, ssm, conv = mamba_layer(x, ssm, conv, p, m, index)
+            else:
+                def body(carry, m):
+                    p = jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(a, m, keepdims=False),
+                        blocks,
+                    )
+                    return mamba_layer(*carry, p, m, index), None
+
+                (x, ssm, conv), _ = jax.lax.scan(
+                    body, (x, ssm, conv), jnp.arange(*seg[1:])
+                )
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        return lm_logits(params, cfg, x)[:, 0], kc, vc, ssm, conv
 
     return step
 
@@ -139,26 +249,37 @@ class CohortDecoder:
         self.slots_fn = slots_fn
         self.lanes = int(lanes)
         self.backend = backend
+        cfg = self.cfg
+        check_supported(cfg)
+        if cfg.family == "mamba_attn":
+            d_inner, _, _, n = ssm_model.mamba2_dims(cfg)
+            lm = len(cfg.ssm_layers)
+            state = dict(ssm_shape=(lm, self.lanes, n, d_inner),
+                         conv_shape=(lm, cfg.ssm_conv - 1, self.lanes, d_inner + 2 * n))
+            row_step = make_hybrid_row_step(cfg, ATTEND[backend], UPDATE[backend])
+        else:
+            state, row_step = {}, make_row_decode_step(cfg, ATTEND[backend])
         self.cache = PagedKVCache(
-            n_layers=self.cfg.n_layers,
+            n_layers=len(cfg.attn_layers) if state else cfg.n_layers,
             lanes=self.lanes,
-            n_kv_heads=self.cfg.n_kv_heads,
-            head_dim=self.cfg.hd,
+            n_kv_heads=cfg.n_kv_heads,
+            head_dim=cfg.hd,
             page_size=page_size,
             dtype=jnp.float32,
+            **state,
         )
         # one jitted fleet step; jax retraces per (rows, seq) bucket.
         # `decode` calls `_step`, which a caller may wrap (a timer, a hook);
-        # `_fleet_step` stays the jitted step itself. The cache is donated on
-        # accelerators, so each step writes it in place; on the CPU donation
-        # is gated off, as fl/pipeline.py gates EXEC_DONATE.
+        # `_fleet_step` stays the jitted step itself. The cache (and the
+        # recurrent state) is donated on accelerators, so each step writes it
+        # in place; on the CPU donation is gated off, as fl/pipeline.py gates
+        # EXEC_DONATE.
+        n_state = len(self.cache.arrays)
         donate = (
             {} if jax.default_backend() == "cpu"
-            else {"donate_argnums": (2, 3)}  # kc, vc
+            else {"donate_argnums": tuple(range(2, 2 + n_state))}  # kc, vc[, ssm, conv]
         )
-        self._fleet_step = jax.jit(
-            jax.vmap(make_row_decode_step(self.cfg, ATTEND[backend])), **donate
-        )
+        self._fleet_step = jax.jit(jax.vmap(row_step), **donate)
         self._step = self._fleet_step
         self.decode_dispatches = 0
         self.tokens: Optional[np.ndarray] = None  # (rows, lanes) last token
@@ -210,8 +331,8 @@ class CohortDecoder:
         Returns (tokens (live_rows, lanes, n_steps) int32,
                  last-step logits (live_rows, lanes, V) float32).
         One jitted dispatch per step for the WHOLE fleet, which consumes the
-        cache it is given (donated): the arrays `cache.k/.v` held before the
-        call are invalid after it. Each stage runs in
+        cache it is given (donated): the arrays `cache.k/.v` (and
+        `cache.ssm/.conv`) held before the call are invalid after it. Each stage runs in
         a span (`repro.utils.trace`): ``decode.prepare`` once, then per step
         ``decode.dispatch`` (the fleet step's call), ``decode.pick`` (the
         next token) and ``decode.fetch`` (the token to the host), and
@@ -233,20 +354,20 @@ class CohortDecoder:
             tok[: len(live)] = self.tokens
             tok = jnp.asarray(tok[:, :, None])  # (R, lanes, 1)
             params = gather_bank_rows(self.params_fn(), slots_p)
-            k, v = self.cache.k, self.cache.v
+            state = self.cache.arrays
             index = jnp.asarray(self.cache.index)
         out = []
         logits = None
         for _ in range(int(n_steps)):
             with span("decode.dispatch"):
-                logits, k, v = self._step(params, tok, k, v, index)
+                logits, *state = self._step(params, tok, *state, index)
             self.decode_dispatches += 1
             with span("decode.pick"):
                 tok, index = pick_tokens(logits, index)
             with span("decode.fetch"):
                 out.append(np.asarray(tok)[:, :, 0])
         with span("decode.writeback"):
-            self.cache.k, self.cache.v = k, v
+            self.cache.arrays = state
             self.cache.index = np.asarray(index, np.int32)
             toks = np.stack(out, axis=-1)  # (R, lanes, n_steps)
             self.tokens = toks[: len(live), :, -1]

@@ -13,15 +13,25 @@ lie. The fleet step donates k/v and returns them updated in place. A
 cache handed in as (R, L, lanes, S, Hkv, hd) is put into storage form
 once, by the next `sync`, and counted in `relayouts`.
 
+Recurrent state (a Mamba2 hybrid, `mamba_attn`): beside the pages of its
+attention layers the cache holds, per row and Mamba2 layer, the SSM state
+`ssm` (R, Lm, lanes, N, H·P) float32 (N leading: the layout
+`kernels/ssm_decode.py` updates in place) and the convolution window `conv`
+(R, Lm, d_conv - 1, lanes, conv channels) float32, a ring in which the
+input of position p lies in slot p % (d_conv - 1), so a step writes one
+slot. Their size does not depend on the position, so `ensure` never grows
+them; the fleet step donates and returns them with k/v.
+
 Partition/merge discipline: `sync(live_slots)` reconciles rows against
 the current leaf slots with the same scatter idiom `spawn_children` uses
 on the bank (`new.at[dst].set(old[src])`) — rows of retained cohorts keep
-their pages and decode positions, rows of retired parents are freed, and
-fresh children start on zeroed pages at position 0.
+their pages, recurrent state and decode positions, rows of retired parents
+are freed, and fresh children start on zeroed pages and a zeroed state at
+position 0 (counted in `state_resets` where there is recurrent state).
 """
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import List, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -40,8 +50,10 @@ class PagedKVCache:
         head_dim: int,
         page_size: int = 128,
         dtype=jnp.float32,
+        ssm_shape: Tuple[int, ...] = (),
+        conv_shape: Tuple[int, ...] = (),
     ):
-        self.L = int(n_layers)
+        self.L = int(n_layers)  # attention layers (the paged ones)
         self.lanes = int(lanes)
         self.Hkv = int(n_kv_heads)
         self.hd = int(head_dim)
@@ -51,6 +63,14 @@ class PagedKVCache:
         self.k = self.v = None      # (R, L, lanes, S, Hkv·hd)
         self.index = np.zeros(0, np.int32)  # per-row decode position
         self.relayouts = 0  # caches put into storage form by `sync`
+        # recurrent state of the Mamba2 layers, (R,) + its shape per row:
+        # ssm (Lm, lanes, N, H·P), conv (Lm, d_conv - 1, lanes, C)
+        self.state_shapes = (
+            {"ssm": tuple(ssm_shape), "conv": tuple(conv_shape)}
+            if ssm_shape else {}
+        )
+        self.ssm = self.conv = None
+        self.state_resets = 0  # rows whose recurrent state `sync` zeroed
 
     # ------------------------------------------------------------- shape
     @property
@@ -69,10 +89,30 @@ class PagedKVCache:
     def nbytes(self) -> int:
         return 0 if self.k is None else int(self.k.nbytes + self.v.nbytes)
 
+    @property
+    def state_nbytes(self) -> int:
+        """Bytes of the recurrent state (SSM state and conv windows)."""
+        return sum(int(a.nbytes) for a in (self.ssm, self.conv) if a is not None)
+
+    @property
+    def arrays(self) -> tuple:
+        """What the fleet step takes and returns: (k, v), then (ssm, conv)
+        where the model has recurrent state."""
+        return (self.k, self.v) + ((self.ssm, self.conv) if self.state_shapes else ())
+
+    @arrays.setter
+    def arrays(self, arrays):
+        self.k, self.v, *state = arrays
+        if state:
+            self.ssm, self.conv = state
+
     def _zeros(self, r: int, s: int):
         return jnp.zeros(
             (r, self.L, self.lanes, s, self.Hkv * self.hd), self.dtype
         )
+
+    def _zero_state(self, r: int, name: str):
+        return jnp.zeros((r,) + self.state_shapes[name], jnp.float32)
 
     def _to_storage(self):
         """k/v handed in as (R, L, lanes, S, Hkv, hd) to storage form: one
@@ -108,6 +148,13 @@ class PagedKVCache:
             new_k = new_k.at[dst].set(self.k[src])
             new_v = new_v.at[dst].set(self.v[src])
             new_index[dst] = self.index[src]
+        if self.state_shapes:
+            state = {n: self._zero_state(r, n) for n in self.state_shapes}
+            if src.size:
+                state = {n: a.at[dst].set(getattr(self, n)[src])
+                         for n, a in state.items()}
+            self.ssm, self.conv = state["ssm"], state["conv"]
+            self.state_resets += len(live) - src.size
         self.k, self.v, self.index, self.slots = new_k, new_v, new_index, live
 
     def ensure(self, extra: int):

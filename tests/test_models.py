@@ -65,7 +65,8 @@ def test_reduced_arch_decode_step(arch, key):
     assert jax.tree.structure(cache) == jax.tree.structure(cache2)
 
 
-@pytest.mark.parametrize("arch", ["granite_3_2b", "qwen3_8b", "h2o_danube_3_4b", "musicgen_large"])
+@pytest.mark.parametrize("arch", ["granite_3_2b", "qwen3_8b", "h2o_danube_3_4b", "musicgen_large",
+                                  "granite_4_0_h_micro"])
 def test_decode_matches_forward(arch, key):
     """Token-by-token decode reproduces the teacher-forced forward logits."""
     cfg = reduce_config(get_config(arch)).replace(attn_qchunk=0)
@@ -109,7 +110,7 @@ def test_attention_qchunk_equivalence(key):
 
 def test_unroll_equivalence(key):
     """scan and unrolled layer stacks produce identical outputs."""
-    for arch in ("zamba2_7b", "xlstm_1_3b", "llama4_maverick_400b_a17b"):
+    for arch in ("zamba2_7b", "xlstm_1_3b", "llama4_maverick_400b_a17b", "granite_4_0_h_micro"):
         cfg = reduce_config(get_config(arch))
         m_scan = build_model(cfg)
         m_unroll = build_model(cfg.replace(unroll=True))
@@ -147,6 +148,7 @@ def test_param_counts_match_targets():
         "starcoder2_15b": (1.3e10, 1.75e10),
         "qwen3_8b": (7.0e9, 9.5e9),
         "xlstm_1_3b": (1.0e9, 2.2e9),
+        "granite_4_0_h_micro": (3.1e9, 3.3e9),  # 3.19 B published
     }
     for arch, (lo, hi) in expectations.items():
         n = build_model(get_config(arch)).param_count()

@@ -9,7 +9,8 @@ plane use, and checks that the compiled program holds the Pallas kernel
 (``tpu_custom_call``).
 
 The paged decode's whole fleet step is compiled too, as the decoder builds
-it for a TPU, to check that it updates the KV cache in place.
+it for a TPU, to check that it updates the KV cache in place, and for
+granite-4.0-h that it updates the Mamba2 state beside it in place.
 
 This is the only test file that loads the TPU compiler: the topology is
 described inside a module fixture (never at import), so under pytest-xdist
@@ -23,7 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels import cosine_sim, decode_attention, segment_aggregate
+from repro.kernels import cosine_sim, decode_attention, segment_aggregate, ssm_decode
 from repro.models import build_model
 from repro.serve import CohortDecoder
 
@@ -173,4 +174,90 @@ def test_fleet_decode_step_updates_cache_in_place(one_chip, monkeypatch):
                 big.append(m.group(1))
     assert not big, big
     # (c) the kernel, labelled as the benchmark's trace reader finds it
+    assert re.search(r"%decode_attention(\.\d+)? = .*tpu_custom_call", text)
+
+
+def test_ssm_decode_compiles(one_chip):
+    """The Mamba2 state update at granite-4.0-h widths (64 heads of 64, state
+    128), 32 lanes, one layer of a 3-layer stack, with the state aliased."""
+    lanes, layers, H, P, N = 32, 3, 64, 64, 128
+    f32 = jnp.float32
+
+    def fn(da, dtx, b, c, state, layer):
+        return ssm_decode.ssm_decode(da, dtx, b, c, state, layer, interpret=False)
+
+    text = _compiled_text(
+        fn, one_chip,
+        ((lanes, H * P), f32), ((lanes, H * P), f32),
+        ((lanes, N), f32), ((lanes, N), f32),
+        ((layers, lanes, N, H * P), f32), ((), jnp.int32),
+    )
+    assert re.search(r"%ssm_decode(\.\d+)? = .*tpu_custom_call", text)
+    assert "output_to_operand_aliasing={{1}: (5, {})}" in text
+
+
+def _in_place_fusions(text):
+    """Fused computations whose root is a dynamic-update-slice: XLA's form of
+    an in-place update, which writes only the slice although its shape is
+    the whole buffer's."""
+    names = set()
+    for m in re.finditer(r"^(%\S+) \(.*?^\}", text, re.M | re.S):
+        root = re.search(r"^\s*ROOT %\S+ = \S+ (\S+?)\(", m.group(0), re.M)
+        if root and root.group(1) == "dynamic-update-slice":
+            names.add(m.group(1))
+    return names
+
+
+def test_hybrid_fleet_decode_step_updates_state_in_place(one_chip, monkeypatch):
+    """granite-4.0-h-micro's fleet step at published widths, one period (10
+    layers: attention at 5, Mamba2 elsewhere), a small vocabulary, 32 lanes
+    and 4,096 positions, as the decoder builds it on a TPU: the KV pages, the
+    SSM state and the conv windows all alias outputs; no copy or fusion
+    writes an f32 buffer of one layer's state or pages or more (a fusion
+    rooted in a dynamic-update-slice writes only its slice in place); both
+    kernels are there under their names."""
+    lanes, seq = 32, 4096
+    cfg = get_config("granite-4.0-h-micro").replace(
+        dtype=jnp.bfloat16, n_layers=10, attn_layers=(5,), vocab=4096)
+    model = build_model(cfg)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dec = CohortDecoder(model, None, lambda: [0], lanes=lanes, page_size=128)
+    shaped = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    bank = jax.eval_shape(lambda k: jax.tree.map(lambda a: a[None], model.init(k)),
+                          jax.random.key(0))
+    bank = jax.tree.map(lambda a: shaped(a.shape, a.dtype), bank)
+    dec.cache.sync([0])
+    dec.cache.ensure(seq)
+    state = [shaped(a.shape, a.dtype) for a in dec.cache.arrays]  # k, v, ssm, conv
+    text = dec._fleet_step.lower(
+        bank, shaped((1, lanes, 1), jnp.int32), *state, shaped((1,), jnp.int32),
+    ).compile().as_text()
+    head = text.splitlines()[0]
+    assert head.startswith("HloModule jit_step,"), head
+    # (a) outputs 1-4 alias the k, v, ssm and conv parameters, which follow
+    # the bank's leaves and the tokens
+    first = len(jax.tree.leaves(bank)) + 1
+    assert "input_output_alias=" in head, "the state is not donated"
+    alias = head.split("input_output_alias=")[1].split("entry_computation_layout")[0]
+    for out in range(1, 5):
+        assert f"{{{out}}}: ({first + out - 1}," in alias, alias
+    # (b) nothing copies a layer's pages or state, or more: the smallest is
+    # one layer's conv window
+    layer_elems = min(a.size // a.shape[1] for a in state)
+    in_place = _in_place_fusions(text)
+    big = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?%(\S+) = (.+?) (copy|copy-start|fusion)\((.*)$", text, re.M):
+        calls = re.search(r"calls=(%[^,\s]+)", m.group(4))
+        if m.group(3) == "fusion" and calls and calls.group(1) in in_place:
+            continue
+        for dims in re.findall(r"f32\[([\d,]+)\]", m.group(2)):
+            n = 1
+            for d in dims.split(","):
+                n *= int(d)
+            if n >= layer_elems:
+                big.append(m.group(1))
+    assert not big, big
+    # (c) the kernels, labelled as the benchmark's trace readers find them
+    assert re.search(r"%ssm_decode(\.\d+)? = .*tpu_custom_call", text)
     assert re.search(r"%decode_attention(\.\d+)? = .*tpu_custom_call", text)
