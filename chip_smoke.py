@@ -281,7 +281,7 @@ def phase_decode(cfg, steps=160, lanes=4, page=128, *, on_tpu, clock=None,
         return CohortDecoder(model, lambda: bank, lambda: [0], lanes=lanes,
                              page_size=page, backend=backend)
 
-    runs, first = {}, {}
+    runs, first, overlapped = {}, {}, {}
     for backend in ("pallas", "ref"):
         dec = decoder(backend)
         dec.sync()
@@ -302,6 +302,9 @@ def phase_decode(cfg, steps=160, lanes=4, page=128, *, on_tpu, clock=None,
         runs[backend] = (toks, np.asarray(logits, np.float32), dec.kv_nbytes,
                          dec.cache.pages, time.perf_counter() - t1)
         check(dec.step_compiles == 1, f"{backend} decode step recompiled")
+        # every token but the last is fetched with the next step in flight
+        overlapped[backend] = dec.overlapped_fetches
+        check(overlapped[backend] == steps - 1, overlapped)
         peaks[backend] = device_peak_bytes()
         del dec, c
     (tok_p, lg_p, kv_nbytes, pages, t_p), (tok_r, lg_r, _, _, t_r) = (
@@ -321,7 +324,7 @@ def phase_decode(cfg, steps=160, lanes=4, page=128, *, on_tpu, clock=None,
     report("C:decode", t0, clock, arch=cfg.arch_id, layers=cfg.n_layers,
            d_model=cfg.d_model, dtype=jnp.dtype(cfg.dtype).name,
            bank_bytes=bank_bytes, steps=steps, lanes=lanes, pages=pages,
-           kv_nbytes=kv_nbytes,
+           kv_nbytes=kv_nbytes, overlapped_fetches=overlapped,
            peak_bytes_in_use=peaks,
            pallas_tok_s=tok_p.size / t_p, ref_tok_s=tok_r.size / t_r,
            kernel_max_err=kernel_err, first_step_logit_err=first_step_err,

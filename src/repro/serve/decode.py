@@ -3,11 +3,12 @@
 The serving-plane fast path for `TransformerTask` cohort models: all live
 cohorts' decode lanes advance one token in ONE jitted dispatch — gather
 each cohort's params row from the (snapshot) stacked bank, vmap a
-single-row decode step over rows, greedy-pick the next token. Attention
-against the paged cache runs through `kernels.ops.decode_attention` (the
-Pallas flash-decode kernel; interpret mode off-TPU) with
-`kernels.ref.decode_attention` as the selectable bit-check oracle —
-backends must produce identical greedy token streams.
+single-row decode step over rows, greedy-pick the next token — with one
+step kept in flight, so that each token's copy to the host overlaps the
+next step on the device. Attention against the paged cache runs through
+`kernels.ops.decode_attention` (the Pallas flash-decode kernel; interpret
+mode off-TPU) with `kernels.ref.decode_attention` as the selectable
+bit-check oracle — backends must produce identical greedy token streams.
 
 The per-row step mirrors `models.transformer.decode_step` for the dense
 family, with the ring-buffer `attention_decode` swapped for a paged
@@ -282,6 +283,7 @@ class CohortDecoder:
         self._fleet_step = jax.jit(jax.vmap(row_step), **donate)
         self._step = self._fleet_step
         self.decode_dispatches = 0
+        self.overlapped_fetches = 0  # token fetches with a later step in flight
         self.tokens: Optional[np.ndarray] = None  # (rows, lanes) last token
 
     @classmethod
@@ -332,16 +334,26 @@ class CohortDecoder:
                  last-step logits (live_rows, lanes, V) float32).
         One jitted dispatch per step for the WHOLE fleet, which consumes the
         cache it is given (donated): the arrays `cache.k/.v` (and
-        `cache.ssm/.conv`) held before the call are invalid after it. Each stage runs in
-        a span (`repro.utils.trace`): ``decode.prepare`` once, then per step
-        ``decode.dispatch`` (the fleet step's call), ``decode.pick`` (the
-        next token) and ``decode.fetch`` (the token to the host), and
-        ``decode.writeback`` once.
+        `cache.ssm/.conv`) held before the call are invalid after it.
+
+        One step is kept in flight: step i is dispatched on token i-1 as the
+        device holds it, and only then is token i-1 copied to the host, so
+        the copy overlaps step i on the device. The host is never more than
+        one step ahead of the tokens it has received; `overlapped_fetches`
+        counts such copies (n_steps - 1 a call).
+
+        Each stage runs in a span (`repro.utils.trace`), in the order
+        ``decode.prepare``; ``decode.dispatch`` (the fleet step's call) and
+        ``decode.pick`` (the next token); (``decode.dispatch``,
+        ``decode.pick``, ``decode.fetch``) for each further step, the fetch
+        copying the token of the step before to the host; ``decode.fetch``
+        of the last token; ``decode.writeback``.
         """
         with span("decode.prepare"):
             self.sync()
             live = self.cache.slots
             assert live, "no live cohorts to decode"
+            assert n_steps >= 1, n_steps
             self.cache.ensure(n_steps + 1)
             r_pad = self.cache.rows
             # pad rows re-use row 0's slot params; their lanes are discarded
@@ -357,15 +369,19 @@ class CohortDecoder:
             state = self.cache.arrays
             index = jnp.asarray(self.cache.index)
         out = []
-        logits = None
-        for _ in range(int(n_steps)):
+        for i in range(int(n_steps)):
             with span("decode.dispatch"):
                 logits, *state = self._step(params, tok, *state, index)
             self.decode_dispatches += 1
+            queued = tok  # token i-1, step i's input
             with span("decode.pick"):
                 tok, index = pick_tokens(logits, index)
-            with span("decode.fetch"):
-                out.append(np.asarray(tok)[:, :, 0])
+            if i:  # token i-1 to the host, step i queued behind its step
+                with span("decode.fetch"):
+                    out.append(np.asarray(queued)[:, :, 0])
+                self.overlapped_fetches += 1
+        with span("decode.fetch"):
+            out.append(np.asarray(tok)[:, :, 0])
         with span("decode.writeback"):
             self.cache.arrays = state
             self.cache.index = np.asarray(index, np.int32)

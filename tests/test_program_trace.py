@@ -69,8 +69,10 @@ def test_span_names_and_nests(tmp_path):
         assert outer[1] <= s <= e <= outer[2]
 
 
-def _tiny_decoder(live=(0, 2)):
-    cfg = reduce_config(get_config("qwen3-8b")).replace(d_model=64, vocab=128, n_layers=2)
+def _tiny_decoder(live=(0, 2), arch="qwen3-8b"):
+    """Two layers at d 64: dense, or for granite-4.0-h-micro one Mamba2
+    layer and one attention layer."""
+    cfg = reduce_config(get_config(arch)).replace(d_model=64, vocab=128, n_layers=2)
     model = build_model(cfg)
     key = jax.random.key(0)
     ps = [model.init(jax.random.fold_in(key, i)) for i in range(3)]
@@ -89,13 +91,16 @@ def test_decode_emits_each_stage_span(tmp_path, steps):
         "auxo:decode.prepare": 1, "auxo:decode.writeback": 1,
         "auxo:decode.dispatch": steps, "auxo:decode.pick": steps,
         "auxo:decode.fetch": steps}
-    # the stages run one after another, in the order of the loop
+    # the stages run one after another, in the order of the loop: step i is
+    # dispatched and picked before token i-1 is fetched, so one step is in
+    # flight at each fetch but the last
     order = [n for n, _, _ in sorted(events, key=lambda e: e[1])
              if n.startswith("auxo:decode.")]
-    assert order == (["auxo:decode.prepare"]
+    assert order == (["auxo:decode.prepare", "auxo:decode.dispatch",
+                      "auxo:decode.pick"]
                      + ["auxo:decode.dispatch", "auxo:decode.pick",
-                        "auxo:decode.fetch"] * steps
-                     + ["auxo:decode.writeback"])
+                        "auxo:decode.fetch"] * (steps - 1)
+                     + ["auxo:decode.fetch", "auxo:decode.writeback"])
     # each stage dispatches one named program: the bank gather and the
     # token pick are no longer per-leaf eager ops
     calls = _count(events, "PjitFunction(")
@@ -126,30 +131,71 @@ def test_decode_program_names():
     assert "module @jit_step " in text
 
 
-def test_decode_tokens_match_per_leaf_gather_and_eager_pick():
-    """The named gather and pick serve the tokens the per-leaf eager
-    indexing and eager argmax served."""
-    dec = _tiny_decoder(live=(2, 0, 1))
-    toks, last = dec.decode(6)
-    ref = _tiny_decoder(live=(2, 0, 1))
+@pytest.mark.parametrize("steps", [1, 6])
+@pytest.mark.parametrize("arch", ["qwen3-8b", "granite-4.0-h-micro"])
+def test_decode_tokens_match_per_leaf_gather_and_eager_pick(arch, steps):
+    """The named gather and pick, with one step in flight, serve exactly the
+    tokens, last logits and positions of a synchronous loop of per-leaf
+    eager indexing, eager argmax and a host copy of each token."""
+    dec = _tiny_decoder(live=(2, 0, 1), arch=arch)
+    toks, last = dec.decode(steps)
+    ref = _tiny_decoder(live=(2, 0, 1), arch=arch)
     ref.sync()
-    ref.cache.ensure(7)
+    ref.cache.ensure(steps + 1)
     live = ref.cache.slots
     slots = np.asarray(live + [live[0]] * (ref.cache.rows - len(live)), np.int64)
     params = jax.tree.map(lambda a: a[slots], ref.params_fn())
     tok = np.zeros((ref.cache.rows, 2), np.int32)
     tok[: len(live)] = ref._seed_tokens()
     tok = jnp.asarray(tok[:, :, None])
-    k, v, index = ref.cache.k, ref.cache.v, jnp.asarray(ref.cache.index)
+    state, index = ref.cache.arrays, jnp.asarray(ref.cache.index)
     out = []
-    for _ in range(6):
-        logits, k, v = ref._step(params, tok, k, v, index)
+    for _ in range(steps):
+        logits, *state = ref._step(params, tok, *state, index)
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, :, None]
         index = index + 1
         out.append(np.asarray(tok)[:, :, 0])
     np.testing.assert_array_equal(toks, np.stack(out, -1)[: len(live)])
     np.testing.assert_array_equal(last, np.asarray(logits)[: len(live)])
     np.testing.assert_array_equal(dec.cache.index, np.asarray(index))
+    for got, want in zip(dec.cache.arrays, state):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_overlapped_fetches_counts_all_tokens_but_the_last():
+    dec = _tiny_decoder()
+    for n, overlapped, dispatches in ((1, 0, 1), (4, 3, 5), (2, 4, 7)):
+        dec.decode(n)
+        assert dec.overlapped_fetches == overlapped
+        assert dec.decode_dispatches == dispatches
+
+
+def test_host_stays_one_step_ahead_of_the_tokens(monkeypatch):
+    """Token i is fetched after step i+1 is dispatched and before step i+2
+    is: at the dispatch of step j the host holds tokens 0..j-2."""
+    import repro.serve.decode as decode_mod
+
+    dec = _tiny_decoder()
+    fetched, held = [], []
+    step, real_span = dec._step, decode_mod.span
+
+    def hooked(*a):
+        held.append(len(fetched))
+        return step(*a)
+
+    def recording(name):
+        if name == "decode.fetch":
+            fetched.append(name)
+        return real_span(name)
+
+    dec._step = hooked
+    monkeypatch.setattr(decode_mod, "span", recording)
+    for n in (1, 5):
+        fetched.clear()
+        held.clear()
+        dec.decode(n)
+        assert held == [0] + list(range(n - 1))
+        assert len(fetched) == n
 
 
 def test_step_compiles_stays_one_under_a_wrapper():
