@@ -5,25 +5,27 @@ cohorts' decode lanes advance one token in ONE jitted dispatch — gather
 each cohort's params row from the (snapshot) stacked bank, vmap a
 single-row decode step over rows, greedy-pick the next token — with one
 step kept in flight, so that each token's copy to the host overlaps the
-next step on the device. Attention against the paged cache runs through
-`kernels.ops.decode_attention` (the Pallas flash-decode kernel; interpret
-mode off-TPU) with `kernels.ref.decode_attention` as the selectable
-bit-check oracle — backends must produce identical greedy token streams.
+next step on the device.
 
-The per-row step mirrors `models.transformer.decode_step` for the dense
-family, with the ring-buffer `attention_decode` swapped for a paged
-append + length-masked kernel call. The cache is updated in place: the
-fleet step donates it (on accelerators), the row step carries the whole
-row cache through its layer loop and writes each layer's new position
-with one `dynamic_update_slice`, and the kernel reads each layer's pages
-where they lie in the stacked cache.
+One row step walks the model's layers in order, as runs of stacked
+layers: a dense model is one run of attention layers; a Mamba2 hybrid
+(`mamba_attn`, granite-4.0-h) walks `models.transformer.layer_segments`,
+runs of Mamba2 layers between single attention layers. Each run is a loop
+over its layer indices that carries the run's state and reads each layer's
+weights where they lie in the stacked blocks (a Python loop under
+`cfg.unroll`, else a `lax.scan`; a run of one layer is applied directly).
 
-A Mamba2 hybrid (`mamba_attn`, granite-4.0-h) runs its layer pattern in
-`make_hybrid_row_step`: the attention layers as above, on their own paged
-cache, and each Mamba2 layer through `kernels.ops.ssm_decode`, which updates
-that layer's recurrent state where it lies in the stacked, donated state
+The state is updated in place: the fleet step donates it (on
+accelerators), and every layer writes its position where it lies. An
+attention layer appends to its paged cache with one `dynamic_update_slice`
+and attends through `kernels.ops.decode_attention` (the Pallas
+flash-decode kernel, which reads the layer's pages where they lie;
+interpret mode off-TPU), with `kernels.ref.decode_attention` as the
+selectable oracle — backends must produce identical greedy token streams.
+A Mamba2 layer updates its recurrent state through `kernels.ops.ssm_decode`
 (`kernels.ref.ssm_decode` is its oracle), beside a convolution window
-written in place with one `dynamic_update_slice`.
+written with one `dynamic_update_slice`. `serve/kv_cache.py` decides the
+state's layout.
 """
 from __future__ import annotations
 
@@ -114,57 +116,15 @@ def _attention_layer(cfg, attend: Callable, lanes: int, index):
     return layer
 
 
-def make_row_decode_step(cfg, attend: Callable):
-    """One cohort row, one decode step. Vmapped over rows by the caller.
-
-    params: one bank row; tokens (lanes, 1) int32; kc/vc (L, lanes, S,
-    Hkv·hd), the row's cache in storage form; index scalar int32 (current
-    position). Returns (logits (lanes, V), kc, vc) with the position
-    written. The cache is carried through the layer loop (not scanned as
-    xs/ys), so XLA updates it in place and never slices a layer out.
-    """
-    check_supported(cfg)
-    assert cfg.family == "dense", "a mamba_attn model steps in make_hybrid_row_step"
-
-    def step(params, tokens, kc, vc, index):
-        x = embed_tokens(params, cfg, tokens)  # (lanes, 1, D)
-        layer = _attention_layer(cfg, attend, tokens.shape[0], index)
-        blocks = params["backbone"]["blocks"]
-        n_layers = kc.shape[0]
-        if cfg.unroll:
-            for l in range(n_layers):
-                x, kc, vc = layer(x, kc, vc, jax.tree.map(lambda a: a[l], blocks), l)
-        else:
-            (x, kc, vc), _ = jax.lax.scan(
-                lambda c, xs: (layer(*c, *xs), None),
-                (x, kc, vc), (blocks, jnp.arange(n_layers)),
-            )
-        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return lm_logits(params, cfg, x)[:, 0], kc, vc
-
-    return step
-
-
-def make_hybrid_row_step(cfg, attend: Callable, update: Callable):
-    """One cohort row, one decode step of a Mamba2 hybrid (`mamba_attn`).
-    Vmapped over rows by the caller.
-
-    params: one bank row; tokens (lanes, 1) int32; kc/vc (La, lanes, S,
-    Hkv·hd), the attention layers' paged cache; ssm (Lm, lanes, N, H·P) and
-    conv (Lm, d_conv - 1, lanes, conv channels), the Mamba2 layers' state
-    (`serve/kv_cache.py`);
-    index scalar int32. Returns (logits (lanes, V), kc, vc, ssm, conv). The
-    layers run in the model's order: each run of Mamba2 layers is a loop
-    over their indices carrying the whole state (a scan over indices, not
-    over the state as xs/ys), each attention layer is applied alone. Every
-    state and page is written where it lies.
-    """
-    check_supported(cfg)
+def _mamba2_layer(cfg, update: Callable, index):
+    """Layer `m` of the recurrent state ssm (Lm, lanes, N, H·P) and conv
+    (Lm, d_conv - 1, lanes, conv channels): a Mamba2 mixer and its MLP at
+    position `index`, the state updated in place. Returns (x, ssm, conv)."""
     d_inner, H, P, N = ssm_model.mamba2_dims(cfg)
     eps = ssm_model.gated_eps(cfg)
     ring = cfg.ssm_conv - 1
 
-    def mamba_layer(x, ssm, conv, p, m, index):
+    def layer(x, ssm, conv, p, m):
         mx = p["mixer"]
         lanes = x.shape[0]
         h = rmsnorm(mx["norm"], x, cfg.norm_eps)[:, 0]  # (lanes, D)
@@ -196,34 +156,80 @@ def make_hybrid_row_step(cfg, attend: Callable, update: Callable):
         x = x + mlp(p["mlp"], rmsnorm(p["mlp_norm"], x, cfg.norm_eps))
         return x, ssm, conv
 
-    def step(params, tokens, kc, vc, ssm, conv, index):
-        x = embed_tokens(params, cfg, tokens)  # (lanes, 1, D)
-        attn_layer = _attention_layer(cfg, attend, tokens.shape[0], index)
-        bb = params["backbone"]
-        blocks = bb["ssm_blocks"]
-        for seg in layer_segments(cfg):
-            if seg[0] == "attn":
-                p = jax.tree.map(lambda a: a[seg[1]], bb["attn_blocks"])
-                x, kc, vc = attn_layer(x, kc, vc, p, seg[1])
-            elif cfg.unroll:
-                for m in range(*seg[1:]):
-                    p = jax.tree.map(lambda a: a[m], blocks)
-                    x, ssm, conv = mamba_layer(x, ssm, conv, p, m, index)
-            else:
-                def body(carry, m):
-                    p = jax.tree.map(
-                        lambda a: jax.lax.dynamic_index_in_dim(a, m, keepdims=False),
-                        blocks,
-                    )
-                    return mamba_layer(*carry, p, m, index), None
+    return layer
 
-                (x, ssm, conv), _ = jax.lax.scan(
-                    body, (x, ssm, conv), jnp.arange(*seg[1:])
-                )
+
+def _runs(cfg):
+    """The model's layers in order, as runs (kind, stacked blocks, first,
+    stop): a dense model is one attention run over `blocks`; a `mamba_attn`
+    model walks `layer_segments`, each attention layer a run of one in
+    `attn_blocks`, each run of Mamba2 layers in `ssm_blocks`."""
+    if cfg.family == "dense":
+        return [("attn", "blocks", 0, cfg.n_layers)]
+    return [("attn", "attn_blocks", s[1], s[1] + 1) if s[0] == "attn"
+            else ("ssm", "ssm_blocks", s[1], s[2]) for s in layer_segments(cfg)]
+
+
+def _walk(cfg, layer, blocks, first, stop, x, state):
+    """Layers first..stop-1 of one run, carrying (x, *state): each layer's
+    weights are read where they lie in the stacked `blocks`."""
+    if cfg.unroll or stop - first == 1:
+        for l in range(first, stop):
+            x, *state = layer(x, *state, jax.tree.map(lambda a: a[l], blocks), l)
+        return x, tuple(state)
+
+    def body(carry, l):
+        # under the fleet step's vmap a `take` is a dynamic-slice with scalar
+        # starts; `dynamic_index_in_dim` would build a start-index vector
+        # for every weight read
+        p = jax.tree.map(lambda a: jnp.take(a, l, axis=0, mode="clip"), blocks)
+        return layer(*carry, p, l), None
+
+    (x, *state), _ = jax.lax.scan(body, (x, *state), jnp.arange(first, stop))
+    return x, tuple(state)
+
+
+def _row_step(cfg, attend: Callable, update: Optional[Callable] = None):
+    """One cohort row, one decode step, for any supported layer pattern.
+    Vmapped over rows by the caller.
+
+    The step takes (params, tokens, *state, index): params one bank row;
+    tokens (lanes, 1) int32; state the row's `PagedKVCache.arrays` in
+    storage form, kc/vc (La, lanes, S, Hkv·hd) then, where the model has
+    Mamba2 layers, ssm and conv (`serve/kv_cache.py`); index scalar int32
+    (current position). It returns (logits (lanes, V), *state) with the
+    position written. The state is carried through each run's loop (not
+    scanned as xs/ys), so XLA updates it in place and never slices a layer
+    out.
+    """
+    check_supported(cfg)
+    runs = _runs(cfg)
+    assert update or all(r[0] == "attn" for r in runs), "Mamba2 layers need `update`"
+
+    def step(params, tokens, *state):
+        *state, index = state
+        x = embed_tokens(params, cfg, tokens)  # (lanes, 1, D)
+        layers = {"attn": _attention_layer(cfg, attend, tokens.shape[0], index)}
+        if update:
+            layers["ssm"] = _mamba2_layer(cfg, update, index)
+        state = {"attn": tuple(state[:2]), "ssm": tuple(state[2:])}
+        for kind, name, first, stop in runs:
+            x, state[kind] = _walk(cfg, layers[kind], params["backbone"][name],
+                                   first, stop, x, state[kind])
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return lm_logits(params, cfg, x)[:, 0], kc, vc, ssm, conv
+        return (lm_logits(params, cfg, x)[:, 0], *state["attn"], *state["ssm"])
 
     return step
+
+
+def make_row_decode_step(cfg, attend: Callable):
+    """The row step of a model without recurrent state (kc, vc)."""
+    return _row_step(cfg, attend)
+
+
+def make_hybrid_row_step(cfg, attend: Callable, update: Callable):
+    """The row step of a Mamba2 hybrid (kc, vc, ssm, conv)."""
+    return _row_step(cfg, attend, update)
 
 
 class CohortDecoder:
@@ -250,25 +256,11 @@ class CohortDecoder:
         self.slots_fn = slots_fn
         self.lanes = int(lanes)
         self.backend = backend
-        cfg = self.cfg
-        check_supported(cfg)
-        if cfg.family == "mamba_attn":
-            d_inner, _, _, n = ssm_model.mamba2_dims(cfg)
-            lm = len(cfg.ssm_layers)
-            state = dict(ssm_shape=(lm, self.lanes, n, d_inner),
-                         conv_shape=(lm, cfg.ssm_conv - 1, self.lanes, d_inner + 2 * n))
-            row_step = make_hybrid_row_step(cfg, ATTEND[backend], UPDATE[backend])
+        self.cache = PagedKVCache(self.cfg, self.lanes, page_size)
+        if self.cache.state_shapes:  # recurrent state beside the pages
+            row_step = make_hybrid_row_step(self.cfg, ATTEND[backend], UPDATE[backend])
         else:
-            state, row_step = {}, make_row_decode_step(cfg, ATTEND[backend])
-        self.cache = PagedKVCache(
-            n_layers=len(cfg.attn_layers) if state else cfg.n_layers,
-            lanes=self.lanes,
-            n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.hd,
-            page_size=page_size,
-            dtype=jnp.float32,
-            **state,
-        )
+            row_step = make_row_decode_step(self.cfg, ATTEND[backend])
         # one jitted fleet step; jax retraces per (rows, seq) bucket.
         # `decode` calls `_step`, which a caller may wrap (a timer, a hook);
         # `_fleet_step` stays the jitted step itself. The cache (and the

@@ -2,9 +2,12 @@
 
 One row of pages per LIVE cohort slot, stacked so the whole fleet decodes
 in one vmapped dispatch: k/v are (R, L, lanes, S, Hkv·hd) with R the
-pow2-bucketed live-cohort count, `lanes` concurrent decode streams per
-cohort, and S a pow2 number of `page_size`-token pages that doubles on
-demand. Resident bytes are therefore ∝ live cohorts — never ∝ N clients.
+pow2-bucketed live-cohort count, L the paged (attention) layers, `lanes`
+concurrent decode streams per cohort, and S a pow2 number of
+`page_size`-token pages that doubles on demand. Resident bytes are
+therefore ∝ live cohorts — never ∝ N clients. This module alone decides
+the decode state's layout: `PagedKVCache(cfg, lanes, page_size)` derives
+every shape and the dtype from the model's configuration.
 
 Storage form: the head axes are flattened into one minor axis of width
 W = Hkv·hd. That shape's device layout is row-major and compact, so the
@@ -31,10 +34,17 @@ position 0 (counted in `state_resets` where there is recurrent state).
 """
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+
+from repro.models.ssm import mamba2_dims
+
+# Every page and all recurrent state are float32: the check's limits in
+# bench/limits/ were calibrated on float32 state, and a bf16 KV or state is
+# a different result, with a check of its own.
+STATE_DTYPE = jnp.float32
 
 
 def _next_pow2(n: int) -> int:
@@ -42,33 +52,29 @@ def _next_pow2(n: int) -> int:
 
 
 class PagedKVCache:
-    def __init__(
-        self,
-        n_layers: int,
-        lanes: int,
-        n_kv_heads: int,
-        head_dim: int,
-        page_size: int = 128,
-        dtype=jnp.float32,
-        ssm_shape: Tuple[int, ...] = (),
-        conv_shape: Tuple[int, ...] = (),
-    ):
-        self.L = int(n_layers)  # attention layers (the paged ones)
+    def __init__(self, cfg, lanes: int, page_size: int = 128):
+        """The decode state of model `cfg`: pages for its attention layers
+        (all `n_layers` of a dense model, the `attn_layers` of a
+        `mamba_attn` one) and, for a `mamba_attn` model, the recurrent
+        state of its Mamba2 layers."""
+        hybrid = cfg.family == "mamba_attn"
+        self.L = len(cfg.attn_layers) if hybrid else cfg.n_layers  # paged layers
+        self.W = cfg.n_kv_heads * cfg.hd
         self.lanes = int(lanes)
-        self.Hkv = int(n_kv_heads)
-        self.hd = int(head_dim)
         self.page_size = int(page_size)
-        self.dtype = dtype
         self.slots: List[int] = []  # row -> cohort bank slot
         self.k = self.v = None      # (R, L, lanes, S, Hkv·hd)
         self.index = np.zeros(0, np.int32)  # per-row decode position
         self.relayouts = 0  # caches put into storage form by `sync`
-        # recurrent state of the Mamba2 layers, (R,) + its shape per row:
-        # ssm (Lm, lanes, N, H·P), conv (Lm, d_conv - 1, lanes, C)
-        self.state_shapes = (
-            {"ssm": tuple(ssm_shape), "conv": tuple(conv_shape)}
-            if ssm_shape else {}
-        )
+        # recurrent state of the Mamba2 layers, (R,) + its shape per row
+        self.state_shapes = {}
+        if hybrid:
+            d_inner, H, P, N = mamba2_dims(cfg)
+            lm = len(cfg.ssm_layers)
+            self.state_shapes = {
+                "ssm": (lm, self.lanes, N, H * P),
+                "conv": (lm, cfg.ssm_conv - 1, self.lanes, d_inner + 2 * N),
+            }
         self.ssm = self.conv = None
         self.state_resets = 0  # rows whose recurrent state `sync` zeroed
 
@@ -107,12 +113,10 @@ class PagedKVCache:
             self.ssm, self.conv = state
 
     def _zeros(self, r: int, s: int):
-        return jnp.zeros(
-            (r, self.L, self.lanes, s, self.Hkv * self.hd), self.dtype
-        )
+        return jnp.zeros((r, self.L, self.lanes, s, self.W), STATE_DTYPE)
 
     def _zero_state(self, r: int, name: str):
-        return jnp.zeros((r,) + self.state_shapes[name], jnp.float32)
+        return jnp.zeros((r,) + self.state_shapes[name], STATE_DTYPE)
 
     def _to_storage(self):
         """k/v handed in as (R, L, lanes, S, Hkv, hd) to storage form: one

@@ -29,6 +29,7 @@ from drivers import decode_hybrid as driver  # noqa: E402
 from reference import granite_hybrid as hybrid  # noqa: E402
 
 from repro.models import build_model  # noqa: E402
+from repro.models.common import ModelConfig  # noqa: E402
 from repro.serve import CohortDecoder  # noqa: E402
 from repro.serve.kv_cache import PagedKVCache  # noqa: E402
 
@@ -126,11 +127,13 @@ def test_decode_from_seeded_state_matches_reference(program, backend):
 
 
 def test_sync_keeps_retained_state_and_zeroes_children():
-    cache = PagedKVCache(n_layers=1, lanes=2, n_kv_heads=1, head_dim=8,
-                         page_size=4, ssm_shape=(2, 2, 4, 8),
-                         conv_shape=(2, 3, 2, 12))
+    # one attention layer between two Mamba2 layers of 1 head of 8, state 4
+    cfg = ModelConfig(arch_id="t", family="mamba_attn", n_layers=3, d_model=4,
+                      n_heads=1, n_kv_heads=1, d_ff=8, vocab=8, head_dim=8,
+                      attn_layers=(1,), ssm_state=4, ssm_heads=1, ssm_conv=4)
+    cache = PagedKVCache(cfg, lanes=2, page_size=4)
     cache.sync([3])
-    assert cache.state_resets == 1 and cache.state_nbytes == 4 * (2 * 2 * 4 * 8 + 2 * 3 * 2 * 12)
+    assert cache.state_resets == 1 and cache.state_nbytes == 4 * (2 * 2 * 4 * 8 + 2 * 3 * 2 * 16)
     key = jax.random.key(0)
     cache.ssm = jax.random.normal(key, cache.ssm.shape)
     cache.conv = jax.random.normal(jax.random.fold_in(key, 1), cache.conv.shape)

@@ -423,8 +423,18 @@ def test_paged_decode_adopts_a_cache_handed_in_head_split_form(backend):
     assert relayouts_split == 1 and relayouts_storage == 0
 
 
-def test_paged_decode_unrolled_layers_match_scanned():
-    model = _tiny_lm()
+def _tiny_hybrid():
+    """granite-4.0-h's pattern, small: two runs of two Mamba2 layers around
+    one attention layer, so that a scanned run has more than one layer."""
+    cfg = reduce_config(get_config("granite-4.0-h-micro")).replace(
+        d_model=64, vocab=128, n_layers=5, attn_layers=(2,)
+    )
+    return build_model(cfg)
+
+
+@pytest.mark.parametrize("make", [_tiny_lm, _tiny_hybrid], ids=["dense", "mamba_attn"])
+def test_paged_decode_unrolled_layers_match_scanned(make):
+    model = make()
     bank = _fake_bank(model)
     unrolled = build_model(model.cfg.replace(unroll=True))
     out = [
@@ -434,6 +444,22 @@ def test_paged_decode_unrolled_layers_match_scanned():
     ]
     np.testing.assert_array_equal(out[0][0], out[1][0])
     np.testing.assert_allclose(out[0][1], out[1][1], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("arch, paged, state", [
+    ("granite-3-2b", 40, {}),
+    ("granite-4.0-h-micro", 4, {"ssm": (36, 1, 128, 4096), "conv": (36, 3, 1, 4352)}),
+], ids=["dense", "mamba_attn"])
+def test_paged_kv_cache_derives_its_layout_from_the_config(arch, paged, state):
+    """Pages for every layer of a dense model and for the attention layers
+    of a Mamba2 hybrid, Hkv·hd wide; the hybrid's SSM state (Lm, lanes, N,
+    H·P) and conv windows (Lm, d_conv - 1, lanes, d_inner + 2N); all f32."""
+    cache = PagedKVCache(get_config(arch), lanes=1, page_size=8)
+    cache.sync([5])
+    assert cache.k.shape == cache.v.shape == (1, paged, 1, 8, 8 * 64)
+    assert {n: getattr(cache, n).shape[1:] for n in state} == state
+    assert len(cache.arrays) == 2 + len(state)
+    assert all(a.dtype == jnp.float32 for a in cache.arrays)
 
 
 def test_paged_kv_partition_scatter_and_cohort_scaling():

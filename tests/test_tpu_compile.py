@@ -9,13 +9,15 @@ plane use, and checks that the compiled program holds the Pallas kernel
 (``tpu_custom_call``).
 
 The paged decode's whole fleet step is compiled too, as the decoder builds
-it for a TPU, to check that it updates the KV cache in place, and for
+it for a TPU, to check that it updates the KV cache in place, that it
+reads each layer's weights in place for more than one cohort row, and for
 granite-4.0-h that it updates the Mamba2 state beside it in place.
 
 This is the only test file that loads the TPU compiler: the topology is
 described inside a module fixture (never at import), so under pytest-xdist
 only the worker given this file loads it.
 """
+import math
 import re
 
 import jax
@@ -129,6 +131,22 @@ def test_decode_attention_stacked_cache_compiles(one_chip, arch):
     assert "tpu_custom_call" in text
 
 
+def _big_writes(text, dtype, elems, skip=()):
+    """Copies and fusions of a compiled program that write a `dtype` buffer
+    of `elems` elements or more, but for fusions that call a computation in
+    `skip`."""
+    big = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?%(\S+) = (.+?) (copy|copy-start|fusion)\((.*)$", text, re.M):
+        calls = re.search(r"calls=(%[^,\s]+)", m.group(4))
+        if calls and calls.group(1) in skip:
+            continue
+        for dims in re.findall(rf"{dtype}\[([\d,]+)\]", m.group(2)):
+            if math.prod(int(d) for d in dims.split(",")) >= elems:
+                big.append(m.group(1))
+    return big
+
+
 def test_fleet_decode_step_updates_cache_in_place(one_chip, monkeypatch):
     """The fleet step at granite-3-2b widths (2 layers, a small vocabulary),
     as the decoder builds it on a TPU: both cache arguments alias outputs,
@@ -162,19 +180,40 @@ def test_fleet_decode_step_updates_cache_in_place(one_chip, monkeypatch):
     alias = head.split("input_output_alias=")[1].split("entry_computation_layout")[0]
     assert f"{{1}}: ({kc}," in alias and f"{{2}}: ({kc + 1}," in alias, alias
     # (b) nothing copies a layer's slice of the cache, or more
-    slice_elems = lanes * seq * cfg.n_kv_heads * cfg.hd
-    big = []
-    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = (.+?) (copy|copy-start|fusion)\(",
-                         text, re.M):
-        for dims in re.findall(r"f32\[([\d,]+)\]", m.group(2)):
-            n = 1
-            for d in dims.split(","):
-                n *= int(d)
-            if n >= slice_elems:
-                big.append(m.group(1))
+    big = _big_writes(text, "f32", lanes * seq * cfg.n_kv_heads * cfg.hd)
     assert not big, big
     # (c) the kernel, labelled as the benchmark's trace reader finds it
     assert re.search(r"%decode_attention(\.\d+)? = .*tpu_custom_call", text)
+
+
+def test_fleet_decode_step_reads_weights_in_place_for_two_rows(one_chip, monkeypatch):
+    """The fleet step of two cohort rows at granite-3-2b widths (4 layers, a
+    small vocabulary), as the decoder builds it on a TPU: each layer's
+    weights are read where they lie in the gathered bank, so no copy or
+    fusion writes a bf16 buffer of a row's whole stack of one weight or
+    more (a walk that scanned the stacked blocks as `xs` moved the layer
+    axis ahead of the row axis, a transposed copy of every weight)."""
+    rows, lanes, seq = 2, 4, 256
+    cfg = get_config("granite-3-2b").replace(dtype=jnp.bfloat16, n_layers=4,
+                                             vocab=4096)
+    model = build_model(cfg)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dec = CohortDecoder(model, None, lambda: list(range(rows)), lanes=lanes)
+    shaped = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    bank = jax.eval_shape(
+        lambda k: jax.tree.map(lambda a: jnp.stack([a] * rows), model.init(k)),
+        jax.random.key(0),
+    )
+    bank = jax.tree.map(lambda a: shaped(a.shape, a.dtype), bank)
+    dec.cache.sync(list(range(rows)))
+    dec.cache.ensure(seq)
+    cache = shaped(dec.cache.k.shape, dec.cache.k.dtype)
+    text = dec._fleet_step.lower(
+        bank, shaped((rows, lanes, 1), jnp.int32), cache, cache,
+        shaped((rows,), jnp.int32),
+    ).compile().as_text()
+    big = _big_writes(text, "bf16", cfg.n_layers * cfg.d_model * cfg.d_ff)
+    assert not big, big
 
 
 def test_ssm_decode_compiles(one_chip):
@@ -244,19 +283,7 @@ def test_hybrid_fleet_decode_step_updates_state_in_place(one_chip, monkeypatch):
     # (b) nothing copies a layer's pages or state, or more: the smallest is
     # one layer's conv window
     layer_elems = min(a.size // a.shape[1] for a in state)
-    in_place = _in_place_fusions(text)
-    big = []
-    for m in re.finditer(
-            r"^\s*(?:ROOT )?%(\S+) = (.+?) (copy|copy-start|fusion)\((.*)$", text, re.M):
-        calls = re.search(r"calls=(%[^,\s]+)", m.group(4))
-        if m.group(3) == "fusion" and calls and calls.group(1) in in_place:
-            continue
-        for dims in re.findall(r"f32\[([\d,]+)\]", m.group(2)):
-            n = 1
-            for d in dims.split(","):
-                n *= int(d)
-            if n >= layer_elems:
-                big.append(m.group(1))
+    big = _big_writes(text, "f32", layer_elems, skip=_in_place_fusions(text))
     assert not big, big
     # (c) the kernels, labelled as the benchmark's trace readers find them
     assert re.search(r"%ssm_decode(\.\d+)? = .*tpu_custom_call", text)
